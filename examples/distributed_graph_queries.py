@@ -1,4 +1,4 @@
-"""Sharded SketchEngine on 8 simulated devices: streamed ingestion with a
+"""Sharded SketchEngine on up to 8 devices: streamed ingestion with a
 mid-stream checkpoint/resume, ring-scheduled Algorithm 2 and distributed
 triangle heavy hitters (Algorithms 4/5), all behind the backend-agnostic
 ``repro.engine`` API — the engine owns the mesh, axis and routing plan
@@ -6,10 +6,13 @@ internally, and each ingested block is scattered to its owner shards
 inside one donated shard_map step.
 
     PYTHONPATH=src python examples/distributed_graph_queries.py
-"""
-import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+Under ``JAX_PLATFORMS=cpu`` the CPU backend is given 8 virtual devices;
+on an accelerator the engine shards over the devices that are visible.
+"""
+from repro.launch import jaxenv
+
+jaxenv.virtual_cpu_devices(8)
 
 import tempfile
 import time
@@ -31,28 +34,29 @@ def main() -> None:
     print(f"kronecker wheel16⊗wheel16: n={n} m={len(edges)} "
           f"T={tri_truth.sum()//3}")
 
-    # Algorithm 1 as a stream: open an empty 8-shard engine, ingest in
+    shards = min(8, jax.device_count())
+    # Algorithm 1 as a stream: open an empty sharded engine, ingest in
     # blocks (each routed to owner shards in one shard_map step), snapshot
     # mid-stream, resume from the checkpoint, finish the stream.
     t0 = time.time()
-    eng = engine.open(n, HLLConfig(p=10), backend="sharded", shards=8)
+    eng = engine.open(n, HLLConfig(p=10), backend="sharded", shards=shards)
     stream = EdgeStream(edges, block=256)
     blocks = list(stream.all_blocks())
     for blk in blocks[: len(blocks) // 2]:
         eng.ingest(blk)
     with tempfile.TemporaryDirectory() as ckpt:
         eng.save(ckpt)
-        eng = engine.load(ckpt)      # restores onto the 8-shard mesh
+        eng = engine.load(ckpt)      # restores onto the same mesh
     print(f"mid-stream snapshot at m={eng.m}; resumed onto "
           f"{eng.shards}-shard mesh")
     for blk in blocks[len(blocks) // 2:]:
         eng.ingest(blk)
     jax.block_until_ready(eng.regs)
-    print(f"streamed accumulate (8 shards): {time.time()-t0:.2f}s")
+    print(f"streamed accumulate ({shards} shards): {time.time()-t0:.2f}s")
 
     # streamed == one-shot build, bit for bit, also when sharded
     batch = engine.build(edges, n, HLLConfig(p=10), backend="sharded",
-                         shards=8)
+                         shards=shards)
     same = np.array_equal(np.asarray(eng.regs), np.asarray(batch.regs))
     print(f"streamed registers == one-shot build: {same}")
 

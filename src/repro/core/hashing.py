@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["fmix32", "hash64", "bucket_rho"]
+__all__ = ["fmix32", "hash64", "bucket_rho", "bucket_rho32"]
 
 _GOLD_HI = np.uint32(0x9E3779B9)  # golden-ratio odd constant (splitmix)
 _GOLD_LO = np.uint32(0x85EBCA6B)
@@ -61,6 +61,14 @@ def bucket_rho(keys: jax.Array, p: int, seed: int = 0) -> tuple[jax.Array, jax.A
     that follows the p bucket bits; q+1 if the window is all zeros. This is
     exactly the paper's xi/rho split with p + q = 64 (Section 4).
     """
+    bucket, rho = bucket_rho32(keys, p, seed)
+    return bucket, rho.astype(jnp.uint8)
+
+
+def bucket_rho32(keys: jax.Array, p: int,
+                 seed: int = 0) -> tuple[jax.Array, jax.Array]:
+    """:func:`bucket_rho` with an int32 rho: the form kernel bodies use,
+    whose scalar unit has no 8-bit integers."""
     if not (1 <= p <= 31):
         raise ValueError(f"p must be in [1, 31], got {p}")
     q = 64 - p
@@ -72,5 +80,4 @@ def bucket_rho(keys: jax.Array, p: int, seed: int = 0) -> tuple[jax.Array, jax.A
     lz_hi = jax.lax.clz(w_hi)
     lz_lo = jax.lax.clz(w_lo)
     lz = jnp.where(w_hi != 0, lz_hi, np.uint32(32) + lz_lo).astype(jnp.int32)
-    rho = jnp.minimum(lz, q) + 1
-    return bucket, rho.astype(jnp.uint8)
+    return bucket, jnp.minimum(lz, q) + 1

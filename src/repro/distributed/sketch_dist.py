@@ -54,17 +54,6 @@ __all__ = [
 ]
 
 
-def _shard_map(body, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """jax.shard_map across jax versions (experimental.shard_map pre-0.6,
-    where ``check_vma`` was called ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check_vma)
-
-
 def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
@@ -299,7 +288,7 @@ def dist_accumulate(mesh: Mesh, axis: str, plan: DistPlan, cfg: HLLConfig,
 
         # pallas_call has no replication rule; the body is purely per-shard
         # anyway, so the check adds nothing here.
-        return jax.jit(_shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis, None), P(axis, None), P(axis, None)),
             out_specs=P(axis, None), check_vma=(impl != "pallas")))
@@ -341,7 +330,7 @@ def dist_propagate_allgather(mesh: Mesh, axis: str, plan: DistPlan,
             return packing.scatter_max_rows(regs_local, dst_local[0],
                                             gathered, layout=layout)
 
-        return jax.jit(_shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis, None), P(axis, None), P(axis, None),
                       P(axis, None)),
@@ -385,7 +374,7 @@ def _propagate_allgather_rep(mesh: Mesh, axis: str, plan: DistPlan,
                 return packing.scatter_max_rows(out, dst_local[0],
                                                 gathered, layout=layout)
 
-            return _shard_map(
+            return jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(axis, None),) * 7 + (P(None, None),),
                 out_specs=P(axis, None))(
@@ -497,7 +486,7 @@ def dist_propagate_ring(mesh: Mesh, axis: str, plan: DistPlan,
                               ring_mask, axis=axis, num=num, layout=layout,
                               overlap=overlap)
 
-        return jax.jit(_shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis, None), P(axis, None, None),
                       P(axis, None, None), P(axis, None, None)),
@@ -533,7 +522,7 @@ def _propagate_ring_rep(mesh: Mesh, axis: str, plan: DistPlan,
                                   ring_mask, axis=axis, num=num,
                                   layout=layout, overlap=overlap)
 
-            return _shard_map(
+            return jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(axis, None), P(axis, None, None),
                           P(axis, None, None), P(axis, None, None),
@@ -627,7 +616,7 @@ def dist_triangle_heavy_hitters(mesh: Mesh, axis: str, plan: DistPlan,
         return total, gvals, alli[gidx]
 
     def build():
-        return jax.jit(_shard_map(
+        return jax.jit(jax.shard_map(
             _body, mesh=mesh,
             in_specs=(P(axis, None), P(axis, None), P(axis, None),
                       P(axis, None)),

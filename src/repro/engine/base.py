@@ -216,9 +216,10 @@ class SketchEngine(abc.ABC):
                  plan_cache: plans.PlanCache | None = None,
                  layout: str = "byte"):
         # capability check, once — includes the layout keyword every op
-        # must accept (DESIGN.md §11) and the family coordinate resolved
-        # from the config's type (DESIGN.md §13)
-        self.kernels = registry.resolve(impl, cfg, layout=layout)
+        # must accept (DESIGN.md §11), the family coordinate resolved
+        # from the config's type (DESIGN.md §13) and the impl's panel bound
+        self.kernels = registry.resolve(impl, cfg, layout=layout,
+                                        rows=regs.shape[0])
         self.family = registry.family(self.kernels.family)
         self._regs = regs
         self.n = int(n)

@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed import sketch_dist as sd
 from repro.engine.base import SketchEngine, bucket
@@ -114,7 +114,16 @@ class ShardedEngine(SketchEngine):
                 f"({jax.device_count()}); set XLA_FLAGS="
                 f"--xla_force_host_platform_device_count=... before "
                 f"importing jax, or lower shards")
-        return jax.make_mesh((shards,), (_AXIS,))
+        # Auto axes: the fused query plans are plain jitted gathers on the
+        # sharded panel and leave the collectives to the partitioner.
+        auto = (AxisType.Auto,)
+        if shards == jax.device_count():
+            # make_mesh orders the devices along the physical ICI ring
+            return jax.make_mesh((shards,), (_AXIS,), axis_types=auto)
+        # a subset (e.g. the survivors of a failover) is no physical TPU
+        # slice, which make_mesh refuses: take the first devices in order
+        return Mesh(np.asarray(jax.devices()[:shards]), (_AXIS,),
+                    axis_types=auto)
 
     @classmethod
     def open(cls, n: int, cfg, *, shards: int | None = None,
@@ -212,7 +221,7 @@ class ShardedEngine(SketchEngine):
             return kernels.accumulate(regs_local, dst_local[0], key[0], cfg,
                                       mask=mask[0])
 
-        f = sd._shard_map(
+        f = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(P(_AXIS, None),) * 4, out_specs=P(_AXIS, None),
             check_vma=(self.impl != "pallas"))

@@ -9,9 +9,15 @@ from __future__ import annotations
 import numpy as np
 
 __all__ = [
-    "adjacency_lists", "neighborhood_truth", "exact_edge_triangles",
+    "degrees", "adjacency_lists", "neighborhood_truth", "exact_edge_triangles",
     "exact_vertex_triangles", "exact_global_triangles", "kron_edge_triangles",
 ]
+
+
+def degrees(n: int, edges: np.ndarray) -> np.ndarray:
+    """Exact degree |N(x)| of every vertex of a canonical edge list."""
+    return (np.bincount(edges[:, 0], minlength=n)
+            + np.bincount(edges[:, 1], minlength=n))
 
 
 def adjacency_lists(n: int, edges: np.ndarray) -> list[np.ndarray]:

@@ -36,11 +36,12 @@ __all__ = ["FALLBACK", "SWEEPS", "device_kind", "cache_key", "tuned_params",
            "resolve_block", "sweep", "clear_cache", "drive_count"]
 
 #: deterministic per-op block sizes used when no swept winner exists
-#: (always, in interpret mode). These are the historical defaults the
-#: kernels shipped with, so interpret-mode behavior is unchanged.
+#: (always, in interpret mode). Edge blocks are multiples of 1024 (the
+#: tile of a 1-D int32 SMEM block in XLA's layout), so the table compiles
+#: for the TPU as it stands.
 FALLBACK: dict[str, dict[str, int]] = {
-    "accumulate": {"edge_block": 512},
-    "propagate": {"edge_block": 512},
+    "accumulate": {"edge_block": 1024},
+    "propagate": {"edge_block": 1024},
     "estimate": {"row_block": 256},
     "union_estimate": {"set_block": 8},
     "intersection_stats": {"pair_block": 64},
@@ -50,11 +51,11 @@ FALLBACK: dict[str, dict[str, int]] = {
 
 #: candidate grid per op; the sweep times each and keeps the fastest.
 SWEEPS: dict[str, list[dict[str, int]]] = {
-    "accumulate": [{"edge_block": b} for b in (128, 256, 512, 1024)],
-    "propagate": [{"edge_block": b} for b in (128, 256, 512, 1024)],
+    "accumulate": [{"edge_block": b} for b in (1024, 2048, 4096)],
+    "propagate": [{"edge_block": b} for b in (1024, 2048, 4096)],
     "estimate": [{"row_block": b} for b in (64, 128, 256, 512)],
-    "union_estimate": [{"set_block": b} for b in (4, 8, 16)],
-    "intersection_stats": [{"pair_block": b} for b in (16, 32, 64, 128)],
+    "union_estimate": [{"set_block": b} for b in (8, 16, 32)],
+    "intersection_stats": [{"pair_block": b} for b in (32, 64, 128)],
     "ertl_stats": [{"pair_block": b} for b in (64, 128, 256)],
     "hip_delta": [{"row_block": b} for b in (64, 128, 256, 512)],
 }

@@ -5,11 +5,12 @@ the register values into [c_a_lt, c_a_gt, c_b_lt, c_b_gt, c_eq] over
 k in [0, q+2). This is the O(E*r) front of every T̃(xy) intersection
 estimate (Algorithms 4/5); the 3-parameter MLE that follows is O(E*q).
 
-TPU design: grid over edge-pair blocks; panels (BE, r) uint8 for a and b in
-VMEM. The comparison masks lt/gt/eq are computed once per panel; the k-loop
-is a static unroll (q+2 iterations) of lane-wise masked reductions — each
-iteration is (BE, r) compares + adds on the VPU, writing one (BE, 1, 5)
-column of the output. No gather, no scatter, no MXU needed; arithmetic
+TPU design: grid over edge-pair blocks; panels (BE, w) uint8 for a and b in
+VMEM, widened to int32 (and unpacked) in the body. The comparison masks
+lt/gt/eq are computed once per panel; the k-loop is a static unroll (q+2
+iterations) of lane-wise masked reductions — each iteration is (BE, r)
+compares + adds on the VPU, giving five columns of one lane-dense
+(BE, 5*(q+2)) output block. No gather, no scatter, no MXU needed; arithmetic
 intensity ~ (q+2) ops/byte keeps it compute-dense for VMEM-resident panels.
 """
 from __future__ import annotations
@@ -20,33 +21,41 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import packing
+from repro.kernels import tiles
 
-__all__ = ["ertl_stats"]
+__all__ = ["ertl_stats", "pair_stats"]
 
 DEFAULT_PAIR_BLOCK = 128
 
 
+def pair_stats(ai: jax.Array, bi: jax.Array, q: int) -> jax.Array:
+    """Eq. 19 histograms of int32 register panels (BE, r) -> (BE, 5*(q+2)).
+
+    Column ``j * (q + 2) + k`` holds statistic j at register value k; the
+    caller reshapes to (BE, 5, q+2). Comparison masks are computed once;
+    the k-loop is a static unroll of lane-wise masked reductions.
+    """
+    # Compare through the difference: Mosaic narrows a compare of two
+    # widened uint8 panels back to uint8, which it cannot lower.
+    d = ai - bi
+    lt = (d < 0).astype(jnp.float32)
+    gt = (d > 0).astype(jnp.float32)
+    eq = (d == 0).astype(jnp.float32)
+    cols = [[] for _ in range(5)]
+    for k in range(q + 2):  # static unroll: k is a compile-time constant
+        a_is_k = (ai == k).astype(jnp.float32)
+        b_is_k = (bi == k).astype(jnp.float32)
+        for j, x in enumerate((a_is_k * lt, a_is_k * gt, b_is_k * gt,
+                               b_is_k * lt, a_is_k * eq)):
+            cols[j].append(jnp.sum(x, axis=1, keepdims=True))
+    return tiles.columns([c for stat in cols for c in stat])
+
+
 def _make_kernel(q: int, layout: str):
     def _kernel(a_ref, b_ref, out_ref):
-        a = a_ref[...]
-        b = b_ref[...]
-        if layout == "packed":
-            a = packing.unpack_rows(a)  # unpack-in-VMEM (DESIGN.md §11)
-            b = packing.unpack_rows(b)
-        ai = a.astype(jnp.int32)
-        bi = b.astype(jnp.int32)
-        lt = (ai < bi).astype(jnp.float32)
-        gt = (ai > bi).astype(jnp.float32)
-        eq = (ai == bi).astype(jnp.float32)
-        for k in range(q + 2):  # static unroll: k is a compile-time constant
-            a_is_k = (ai == k).astype(jnp.float32)
-            b_is_k = (bi == k).astype(jnp.float32)
-            out_ref[:, 0, k] = jnp.sum(a_is_k * lt, axis=1)
-            out_ref[:, 1, k] = jnp.sum(a_is_k * gt, axis=1)
-            out_ref[:, 2, k] = jnp.sum(b_is_k * gt, axis=1)
-            out_ref[:, 3, k] = jnp.sum(b_is_k * lt, axis=1)
-            out_ref[:, 4, k] = jnp.sum(a_is_k * eq, axis=1)
+        ai = tiles.unpack(a_ref[...].astype(jnp.int32), layout)
+        bi = tiles.unpack(b_ref[...].astype(jnp.int32), layout)
+        out_ref[...] = pair_stats(ai, bi, q)
     return _kernel
 
 
@@ -55,21 +64,23 @@ def _make_kernel(q: int, layout: str):
 def ertl_stats(a: jax.Array, b: jax.Array, q: int,
                *, layout: str = "byte",
                pair_block: int = DEFAULT_PAIR_BLOCK,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool) -> jax.Array:
     """a, b: uint8[E, w] (E multiple of pair_block) -> float32[E, 5, q+2]."""
     e, r = a.shape
     assert a.shape == b.shape
     assert e % pair_block == 0, (e, pair_block)
     grid = (e // pair_block,)
-    return pl.pallas_call(
+    k = 5 * (q + 2)
+    out = pl.pallas_call(
         _make_kernel(q, layout),
         grid=grid,
         in_specs=[
             pl.BlockSpec((pair_block, r), lambda i: (i, 0)),
             pl.BlockSpec((pair_block, r), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((pair_block, 5, q + 2), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((e, 5, q + 2), jnp.float32),
+        out_specs=pl.BlockSpec((pair_block, k), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((e, k), jnp.float32),
         interpret=interpret,
         name="ertl_stats",
     )(a, b)
+    return out.reshape(e, 5, q + 2)

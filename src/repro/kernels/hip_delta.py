@@ -28,17 +28,18 @@ DEFAULT_ROW_BLOCK = 256
 
 
 def _kernel(prev_ref, cur_ref, out_ref):
-    prev = prev_ref[...]
-    cur = cur_ref[...]
+    # uint8 lanes have no vector compare or reduction: widen first
+    prev = prev_ref[...].astype(jnp.int32)
+    cur = cur_ref[...].astype(jnp.int32)
     inv_p = jnp.exp2(prev.astype(jnp.float32))
     grew = (cur > prev).astype(jnp.float32)
-    out_ref[:, 0] = jnp.sum(inv_p * grew, axis=1)
+    out_ref[...] = jnp.sum(inv_p * grew, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("row_block", "interpret"))
 def hip_delta_rows(prev: jax.Array, cur: jax.Array, *,
                    row_block: int = DEFAULT_ROW_BLOCK,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool) -> jax.Array:
     """prev/cur: uint8[N, r] (N multiple of row_block) -> float32[N]."""
     n, r = prev.shape
     assert prev.shape == cur.shape, (prev.shape, cur.shape)

@@ -5,10 +5,10 @@ z = #zero registers, fused in one pass over the register panel. The O(N)
 estimator tail (alpha*r^2/s vs linear counting vs beta) stays outside — it
 is negligible and branchy.
 
-TPU design: grid over row blocks; each block is a (BN, r) uint8 panel in
-VMEM reduced lane-wise by the VPU (exp2 of a uint8 upcast is a cheap
-transcendental; reductions along lanes). Output is a (BN, 2) f32 panel
-(s in column 0, z in column 1) to keep the store 2-D and lane-aligned.
+TPU design: grid over row blocks; each block is a (BN, w) uint8 panel in
+VMEM, widened to int32 and reduced lane-wise by the VPU (exp2 of the
+upcast is a cheap transcendental; reductions along lanes). Output is a
+(BN, 2) f32 panel (s in column 0, z in column 1), written as one block.
 """
 from __future__ import annotations
 
@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import packing
+from repro.kernels import tiles
 
 __all__ = ["hll_estimate_stats"]
 
@@ -27,16 +27,10 @@ DEFAULT_ROW_BLOCK = 256
 
 def _make_kernel(layout: str):
     def _kernel(regs_ref, out_ref):
-        regs = regs_ref[...]
-        if layout == "packed":
-            # unpack-in-VMEM (DESIGN.md §11): HBM moved the half-width
-            # panel; the full-width lanes exist only inside this block.
-            regs = packing.unpack_rows(regs)
-        x = regs.astype(jnp.float32)
-        s = jnp.sum(jnp.exp2(-x), axis=1)
-        z = jnp.sum((x == 0.0).astype(jnp.float32), axis=1)
-        out_ref[:, 0] = s
-        out_ref[:, 1] = z
+        # unpack-in-VMEM (DESIGN.md §11): HBM moved the half-width panel;
+        # the full-width lanes exist only inside this block.
+        regs = tiles.unpack(regs_ref[...].astype(jnp.int32), layout)
+        out_ref[...] = tiles.columns(list(tiles.harmonic(regs)))
     return _kernel
 
 
@@ -44,7 +38,7 @@ def _make_kernel(layout: str):
                                              "interpret"))
 def hll_estimate_stats(regs: jax.Array, *, layout: str = "byte",
                        row_block: int = DEFAULT_ROW_BLOCK,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """regs: uint8[N, w] (N multiple of row_block) -> float32[N, 2] = (s, z)."""
     n, r = regs.shape
     assert n % row_block == 0, (n, row_block)

@@ -9,12 +9,12 @@ merged register panels live only in VMEM scratch; the old path
 materialized both (B, r) gather panels in HBM before the separate
 ``ertl_stats`` and estimate programs re-read them.
 
-TPU design: register panel (V, r) pinned in VMEM; pair endpoints as SMEM
-scalars. Each grid step gathers its pair block into two (pair_block, r)
-VMEM scratch panels with a fori_loop of (1, r) row copies, then runs the
-vectorized panel math of the ``ertl_stats`` kernel (comparison masks once,
-a static q+2 unroll of lane-wise masked reductions) plus the three (s, z)
-reductions — all VPU work on VMEM-resident panels, no gather HLO.
+TPU design: register panel (V, w) pinned in VMEM; pair endpoints as SMEM
+scalars. Each grid step gathers its pair block into two int32
+(pair_block, w) VMEM scratch panels with a fori_loop of aligned-tile row copies
+(``kernels.tiles``), then runs the vectorized panel math of the
+``ertl_stats`` kernel (``pair_stats``) plus the three (s, z) reductions —
+all VPU work on VMEM-resident panels, no gather HLO.
 """
 from __future__ import annotations
 
@@ -25,7 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import packing
+from repro.kernels import tiles
+from repro.kernels.ertl_stats import pair_stats
 
 __all__ = ["intersection_stats"]
 
@@ -33,76 +34,59 @@ DEFAULT_PAIR_BLOCK = 64
 
 
 def _make_kernel(q: int, layout: str):
-    def _kernel(regs_ref, pa_ref, pb_ref, stats_ref, sz_ref, a_ref, b_ref):
+    def _kernel(regs_ref, pairs_ref, stats_ref, sz_ref, a_ref, b_ref):
         def gather(e, _):
-            ra = pl.load(regs_ref, (pl.dslice(pa_ref[e], 1), slice(None)))
-            pl.store(a_ref, (pl.dslice(e, 1), slice(None)), ra)
-            rb = pl.load(regs_ref, (pl.dslice(pb_ref[e], 1), slice(None)))
-            pl.store(b_ref, (pl.dslice(e, 1), slice(None)), rb)
+            tiles.put_row(a_ref, e, tiles.read_row(regs_ref, pairs_ref[e, 0]))
+            tiles.put_row(b_ref, e, tiles.read_row(regs_ref, pairs_ref[e, 1]))
             return 0
 
-        jax.lax.fori_loop(0, pa_ref.shape[0], gather, 0)
-        a = a_ref[...]
-        b = b_ref[...]
-        if layout == "packed":
-            # The gather moved half-width packed rows; the histogram and
-            # (s, z) math needs register values, so unpack in VMEM (§11).
-            a = packing.unpack_rows(a)
-            b = packing.unpack_rows(b)
-        ai = a.astype(jnp.int32)
-        bi = b.astype(jnp.int32)
-        lt = (ai < bi).astype(jnp.float32)
-        gt = (ai > bi).astype(jnp.float32)
-        eq = (ai == bi).astype(jnp.float32)
-        for k in range(q + 2):  # static unroll: k is a compile-time constant
-            a_is_k = (ai == k).astype(jnp.float32)
-            b_is_k = (bi == k).astype(jnp.float32)
-            stats_ref[:, 0, k] = jnp.sum(a_is_k * lt, axis=1)
-            stats_ref[:, 1, k] = jnp.sum(a_is_k * gt, axis=1)
-            stats_ref[:, 2, k] = jnp.sum(b_is_k * gt, axis=1)
-            stats_ref[:, 3, k] = jnp.sum(b_is_k * lt, axis=1)
-            stats_ref[:, 4, k] = jnp.sum(a_is_k * eq, axis=1)
-        for col, panel in enumerate((ai, bi, jnp.maximum(ai, bi))):
-            x = panel.astype(jnp.float32)
-            sz_ref[:, col, 0] = jnp.sum(jnp.exp2(-x), axis=1)
-            sz_ref[:, col, 1] = jnp.sum((x == 0.0).astype(jnp.float32),
-                                        axis=1)
+        jax.lax.fori_loop(0, pairs_ref.shape[0], gather, 0)
+        # The gather moved w-byte rows; the histogram and (s, z) math needs
+        # register values, so unpack in VMEM (§11).
+        ai = tiles.unpack(a_ref[...], layout)
+        bi = tiles.unpack(b_ref[...], layout)
+        stats_ref[...] = pair_stats(ai, bi, q)
+        sz = [c for panel in (ai, bi, jnp.maximum(ai, bi))
+              for c in tiles.harmonic(panel)]
+        sz_ref[...] = tiles.columns(sz)
     return _kernel
 
 
 @functools.partial(jax.jit,
                    static_argnames=("q", "layout", "pair_block", "interpret"))
-def intersection_stats(regs: jax.Array, pa: jax.Array, pb: jax.Array, q: int,
+def intersection_stats(regs: jax.Array, pairs: jax.Array, q: int,
                        *, layout: str = "byte",
                        pair_block: int = DEFAULT_PAIR_BLOCK,
-                       interpret: bool = True) -> tuple[jax.Array, jax.Array]:
-    """regs: uint8[V, w]; pa/pb: int32[B] (B a multiple of pair_block) ->
-    (float32[B, 5, q+2] Eq. 19 stats, float32[B, 3, 2] (s, z) panels)."""
+                       interpret: bool) -> tuple[jax.Array, jax.Array]:
+    """regs: uint8[V, w]; pairs: int32[B, 2] endpoints (B a multiple of
+    pair_block, V of ``tiles.tile_rows(uint8)``) -> (float32[B, 5, q+2]
+    Eq. 19 stats, float32[B, 3, 2] (s, z) panels)."""
     v, r = regs.shape
-    b = pa.shape[0]
-    assert pa.shape == pb.shape, (pa.shape, pb.shape)
+    b = pairs.shape[0]
     assert b % pair_block == 0, (b, pair_block)
+    assert v % tiles.tile_rows(regs.dtype) == 0, v
     grid = (b // pair_block,)
-    return pl.pallas_call(
+    k = 5 * (q + 2)
+    stats, sz = pl.pallas_call(
         _make_kernel(q, layout),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((v, r), lambda i: (0, 0)),  # panel pinned in VMEM
-            pl.BlockSpec((pair_block,), lambda i: (i,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((pair_block,), lambda i: (i,),
+            tiles.pinned((v, r)),  # panel pinned in VMEM
+            pl.BlockSpec((pair_block, 2), lambda i: (i, 0),
                          memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            pl.BlockSpec((pair_block, 5, q + 2), lambda i: (i, 0, 0)),
-            pl.BlockSpec((pair_block, 3, 2), lambda i: (i, 0, 0)),
+            pl.BlockSpec((pair_block, k), lambda i: (i, 0)),
+            pl.BlockSpec((pair_block, 6), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, 5, q + 2), jnp.float32),
-            jax.ShapeDtypeStruct((b, 3, 2), jnp.float32),
+            jax.ShapeDtypeStruct((b, k), jnp.float32),
+            jax.ShapeDtypeStruct((b, 6), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((pair_block, r), jnp.uint8),
-                        pltpu.VMEM((pair_block, r), jnp.uint8)],
+        scratch_shapes=[pltpu.VMEM((pair_block, r), jnp.int32),
+                        pltpu.VMEM((pair_block, r), jnp.int32)],
+        compiler_params=tiles.COMPILER_PARAMS,
         interpret=interpret,
         name="intersection_stats",
-    )(regs, pa.astype(jnp.int32), pb.astype(jnp.int32))
+    )(regs, pairs.astype(jnp.int32))
+    return stats.reshape(b, 5, q + 2), sz.reshape(b, 3, 2)

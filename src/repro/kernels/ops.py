@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from repro.core import hll
 from repro.core.hashing import bucket_rho
 from repro.core.hll import HLLConfig
-from repro.kernels import autotune, packing, ref, registry
+from repro.kernels import autotune, packing, ref, registry, tiles
 from repro.kernels.hll_accumulate import hll_accumulate as _acc_kernel
 from repro.kernels.hll_propagate import hll_propagate as _prop_kernel
 from repro.kernels.hll_estimate import hll_estimate_stats as _est_kernel
@@ -55,6 +55,12 @@ def _pad_to(x: jax.Array, mult: int, fill) -> jax.Array:
     if pad == 0:
         return x
     return jnp.concatenate([x, jnp.full((pad,) + x.shape[1:], fill, x.dtype)])
+
+
+def _pad_rows(regs: jax.Array) -> jax.Array:
+    """Pad a panel's rows to the kernels' aligned row tile (engines already
+    allocate aligned tables, so this copies only for odd direct calls)."""
+    return _pad_to(regs, tiles.tile_rows(regs.dtype), 0)
 
 
 def _blk(op: str, name: str, value: int | None) -> int:
@@ -98,9 +104,10 @@ def _accumulate_pallas(regs, rows, keys, mask, *, cfg, layout="byte",
     if mask is None:
         mask = jnp.ones((e,), bool)
     mask = _pad_to(mask, edge_block, False)
-    return _acc_kernel(regs, rows, keys, mask, p=cfg.p, seed=cfg.seed,
-                       layout=layout, edge_block=edge_block,
-                       interpret=registry.interpret_mode())
+    out = _acc_kernel(_pad_rows(regs), rows, keys, mask, p=cfg.p,
+                      seed=cfg.seed, layout=layout, edge_block=edge_block,
+                      interpret=registry.interpret_mode())
+    return out[:regs.shape[0]]
 
 
 def accumulate(regs: jax.Array, rows: jax.Array, keys: jax.Array,
@@ -166,8 +173,10 @@ def _propagate_pallas(regs, src, dst, mask, *, layout="byte",
     edge_block = _blk("propagate", "edge_block", edge_block)
     src = _pad_to(src.astype(jnp.int32), edge_block, 0)
     dst = _pad_to(dst.astype(jnp.int32), edge_block, 0)
-    return _prop_kernel(regs, src, dst, layout=layout, edge_block=edge_block,
-                        interpret=registry.interpret_mode())
+    out = _prop_kernel(_pad_rows(regs), src, dst, layout=layout,
+                       edge_block=edge_block,
+                       interpret=registry.interpret_mode())
+    return out[:regs.shape[0]]
 
 
 def propagate(regs: jax.Array, src: jax.Array, dst: jax.Array,
@@ -238,7 +247,7 @@ def _union_estimate_pallas(regs, ids, mask, *, layout="byte", set_block=None):
     b = ids.shape[0]
     ids_p = _pad_to(ids.astype(jnp.int32), set_block, 0)
     mask_p = _pad_to(mask, set_block, False)
-    stats = _union_kernel(regs, ids_p, mask_p, layout=layout,
+    stats = _union_kernel(_pad_rows(regs), ids_p, mask_p, layout=layout,
                           set_block=set_block,
                           interpret=registry.interpret_mode())
     return stats[:b, 0], stats[:b, 1]
@@ -267,21 +276,20 @@ def union_estimate(regs: jax.Array, ids: jax.Array, mask: jax.Array,
 
 # ------------------------------------------------------- intersection_stats
 @registry.register("intersection_stats", "ref")
-def _intersection_stats_ref(regs, pa, pb, q, *, layout="byte",
+def _intersection_stats_ref(regs, pairs, q, *, layout="byte",
                             pair_block=None):
     if layout == "packed":
         regs = packing.unpack_rows(regs)
-    return ref.intersection_stats_ref(regs, pa, pb, q)
+    return ref.intersection_stats_ref(regs, pairs[:, 0], pairs[:, 1], q)
 
 
 @registry.register("intersection_stats", "pallas")
-def _intersection_stats_pallas(regs, pa, pb, q, *, layout="byte",
+def _intersection_stats_pallas(regs, pairs, q, *, layout="byte",
                                pair_block=None):
     pair_block = _blk("intersection_stats", "pair_block", pair_block)
-    b = pa.shape[0]
-    pa_p = _pad_to(pa.astype(jnp.int32), pair_block, 0)
-    pb_p = _pad_to(pb.astype(jnp.int32), pair_block, 0)
-    stats, sz = _inter_kernel(regs, pa_p, pb_p, q, layout=layout,
+    b = pairs.shape[0]
+    pairs_p = _pad_to(pairs.astype(jnp.int32), pair_block, 0)
+    stats, sz = _inter_kernel(_pad_rows(regs), pairs_p, q, layout=layout,
                               pair_block=pair_block,
                               interpret=registry.interpret_mode())
     return stats[:b], sz[:b]
@@ -304,8 +312,7 @@ def intersection_stats(regs: jax.Array, pairs: jax.Array, cfg: HLLConfig,
                                         pair_block, p=cfg.p, impl=impl,
                                         layout=layout)
     fn = registry.lookup("intersection_stats", impl, family)
-    return fn(regs, pairs[:, 0], pairs[:, 1], cfg.q, layout=layout,
-              pair_block=pair_block)
+    return fn(regs, pairs, cfg.q, layout=layout, pair_block=pair_block)
 
 
 # --------------------------------------------------------------- ertl_stats

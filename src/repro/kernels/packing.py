@@ -27,8 +27,9 @@ insert); past that point the packed estimate is biased low by at most
 need exactness at extreme cardinalities use ``layout="byte"``.
 
 Every function here is pure jnp on arrays, so the same helpers run on
-host panels, inside jitted plans, and inside Pallas kernel bodies on
-VMEM-resident blocks (the in-kernel unpack of DESIGN.md §11).
+host panels and inside jitted plans. Pallas kernel bodies unpack and
+merge in VMEM with the int32 forms of ``kernels.tiles`` (the in-kernel
+unpack of DESIGN.md §11): Mosaic has no vector shifts or max on uint8.
 """
 from __future__ import annotations
 

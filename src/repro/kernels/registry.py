@@ -38,11 +38,12 @@ from dataclasses import dataclass
 
 import jax
 
-from repro.kernels.packing import LAYOUTS, validate_layout
+from repro.kernels import tiles
+from repro.kernels.packing import LAYOUTS, row_width, validate_layout
 
-__all__ = ["OPS", "LAYOUTS", "register", "lookup", "impls", "resolve",
-           "KernelSet", "interpret_mode", "SketchFamily", "register_family",
-           "family", "families", "family_of"]
+__all__ = ["OPS", "LAYOUTS", "PANEL_LIMITS", "register", "lookup", "impls",
+           "resolve", "KernelSet", "interpret_mode", "SketchFamily",
+           "register_family", "family", "families", "family_of"]
 
 #: op names a complete **hll** kernel implementation provides (the §4 hot
 #: paths, including the §10 fused query-estimation ops). Kept as the
@@ -55,6 +56,10 @@ OPS = ("accumulate", "propagate", "estimate", "ertl_stats",
 #: impl that cannot accept one would silently merge padding, so resolve()
 #: rejects it up front.
 MASKED_OPS = ("accumulate", "propagate", "union_estimate")
+
+#: impls whose kernels pin the whole register panel in VMEM, and the
+#: largest panel (bytes) they compile for on the TPU.
+PANEL_LIMITS = {"pallas": tiles.PANEL_VMEM_BYTES}
 
 _REGISTRY: dict[tuple[str, str, str], object] = {}
 _FAMILIES: dict[str, "SketchFamily"] = {}
@@ -367,7 +372,8 @@ class KernelSet:
 
 
 def resolve(impl: str, cfg=None, layout: str = "byte",
-            family: str | None = None) -> KernelSet:
+            family: str | None = None,
+            rows: int | None = None) -> KernelSet:
     """Capability-check ``impl`` against a family's ops; bundle a KernelSet.
 
     Raises ``ValueError`` (naming the registered impls) if ``impl`` does
@@ -380,7 +386,9 @@ def resolve(impl: str, cfg=None, layout: str = "byte",
     one the family's semantics tolerate (ADS is byte-only, DESIGN.md
     §13), and every registered op must accept a ``layout`` keyword so a
     packed engine cannot reach an impl that would misread half-width
-    panels.
+    panels. ``rows`` (with ``cfg``) is the register panel's row count:
+    an impl in :data:`PANEL_LIMITS` refuses a panel larger than its
+    bound with a ``ValueError`` naming it, before any kernel compiles.
     """
     _ensure_builtins()
     validate_layout(layout)
@@ -430,6 +438,15 @@ def resolve(impl: str, cfg=None, layout: str = "byte",
                 f"{op} impl {impl!r} does not accept a 'layout' argument; "
                 f"engines thread the register-panel layout through every "
                 f"op (DESIGN.md §11; signature: {sig})")
+    limit = PANEL_LIMITS.get(impl)
+    if rows is not None and limit is not None:
+        nbytes = rows * row_width(cfg.r, layout)
+        if nbytes > limit:
+            raise ValueError(
+                f"impl {impl!r} pins the register panel in VMEM: {rows} rows "
+                f"x {row_width(cfg.r, layout)} bytes = {nbytes} bytes "
+                f"exceeds the bound of {limit} bytes "
+                f"(registry.PANEL_LIMITS); use impl='ref' or fewer rows")
     estimator = (getattr(cfg, "estimator", fam.default_estimator)
                  if cfg else fam.default_estimator)
     fallback = fam.resolve_fallback(estimator)

@@ -7,16 +7,17 @@ pass, without the merged register panel ever leaving the chip. The O(B)
 estimator combination (Flajolet / linear counting / beta) stays outside
 the kernel behind the ``hll.estimate_from_stats`` seam.
 
-TPU design: the register panel (V, r) is pinned in VMEM for the whole grid
-(same contract as accumulate/propagate: caller bounds V*r per shard); ids
-and masks are scalars in SMEM. Each grid step owns a block of set rows and
-a (set_block, r) VMEM scratch: a fori_loop walks the block's lanes doing
-(1, r) row loads max-accumulated into the scratch — masked lanes multiply
-the row by 0, so padding merges the empty row (never vertex 0's sketch) —
-then one vectorized VPU reduction turns the merged panel into the (s, z)
-output columns. HBM traffic is r bytes per *member*, in and nothing out
-but 8 bytes per set; the old two-pass path wrote and re-read the whole
-merged (B, r) panel between its gather and estimate programs.
+TPU design: the register panel (V, w) is pinned in VMEM for the whole grid
+(same contract as accumulate/propagate: ``registry.resolve`` bounds its
+bytes); the ids are scalars in SMEM, a masked lane carrying -1. Each grid
+step owns a block of set rows: a fori_loop walks the block's lanes,
+reading each member row out of its aligned tile (``kernels.tiles``) and
+max-merging it into an int32 (set_block, w) carry — a masked lane merges
+the empty row (never vertex 0's sketch) — then one vectorized VPU
+reduction turns the merged panel into the (s, z) output columns. HBM
+traffic is w bytes per *member*, in and nothing out but 8 bytes per set;
+the old two-pass path wrote and re-read the whole merged (B, r) panel
+between its gather and estimate programs.
 """
 from __future__ import annotations
 
@@ -27,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import packing
+from repro.kernels import tiles
 
 __all__ = ["union_estimate_stats"]
 
@@ -35,32 +36,21 @@ DEFAULT_SET_BLOCK = 8
 
 
 def _make_kernel(layout: str):
-    # Packed scratch merges nibble-wise; masking by `row * keep` stays
-    # valid because the all-zero byte is the packed empty row too.
-    merge = packing.max_rows if layout == "packed" else jnp.maximum
-
-    def _kernel(regs_ref, ids_ref, mask_ref, out_ref, acc_ref):
+    def _kernel(regs_ref, ids_ref, out_ref):
         bb, lanes = ids_ref.shape
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        sub = jax.lax.broadcasted_iota(jnp.int32, (bb, regs_ref.shape[1]), 0)
 
-        def member(e, _):
+        def member(e, acc):
             b = e // lanes
-            li = e % lanes
-            keep = mask_ref[b, li].astype(jnp.uint8)
-            row = pl.load(regs_ref,
-                          (pl.dslice(ids_ref[b, li], 1), slice(None)))
-            cur = pl.load(acc_ref, (pl.dslice(b, 1), slice(None)))
-            pl.store(acc_ref, (pl.dslice(b, 1), slice(None)),
-                     merge(cur, row * keep))
-            return 0
+            gid = ids_ref[b, e % lanes]  # < 0: masked lane
+            row = tiles.read_row(regs_ref, jnp.maximum(gid, 0))
+            row = jnp.where((sub == b) & (gid >= 0), row, 0)
+            return tiles.merge(acc, row, layout)
 
-        jax.lax.fori_loop(0, bb * lanes, member, 0)
-        acc = acc_ref[...]
-        if layout == "packed":
-            acc = packing.unpack_rows(acc)  # unpack-in-VMEM (§11)
-        x = acc.astype(jnp.float32)
-        out_ref[:, 0] = jnp.sum(jnp.exp2(-x), axis=1)
-        out_ref[:, 1] = jnp.sum((x == 0.0).astype(jnp.float32), axis=1)
+        acc = jax.lax.fori_loop(0, bb * lanes, member,
+                                jnp.zeros(sub.shape, jnp.int32))
+        s, z = tiles.harmonic(tiles.unpack(acc, layout))
+        out_ref[...] = tiles.columns([s, z])
     return _kernel
 
 
@@ -69,27 +59,29 @@ def _make_kernel(layout: str):
 def union_estimate_stats(regs: jax.Array, ids: jax.Array, mask: jax.Array,
                          *, layout: str = "byte",
                          set_block: int = DEFAULT_SET_BLOCK,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool) -> jax.Array:
     """regs: uint8[V, w]; ids: int32[B, L]; mask: bool[B, L] (B a multiple
-    of set_block) -> float32[B, 2] = (s, z) of each masked union row."""
+    of set_block, V of ``tiles.tile_rows(uint8)``) -> float32[B, 2] =
+    (s, z) of each masked union row."""
     v, r = regs.shape
     b, lanes = ids.shape
     assert mask.shape == (b, lanes), (mask.shape, ids.shape)
     assert b % set_block == 0, (b, set_block)
+    assert v % tiles.tile_rows(regs.dtype) == 0, v
     grid = (b // set_block,)
+    # the mask rides in the ids: a masked lane carries -1
+    ids_m = jnp.where(mask, ids.astype(jnp.int32), -1)
     return pl.pallas_call(
         _make_kernel(layout),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((v, r), lambda i: (0, 0)),  # panel pinned in VMEM
-            pl.BlockSpec((set_block, lanes), lambda i: (i, 0),
-                         memory_space=pltpu.SMEM),
+            tiles.pinned((v, r)),  # panel pinned in VMEM
             pl.BlockSpec((set_block, lanes), lambda i: (i, 0),
                          memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((set_block, 2), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, 2), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((set_block, r), jnp.uint8)],
+        compiler_params=tiles.COMPILER_PARAMS,
         interpret=interpret,
         name="union_estimate_stats",
-    )(regs, ids.astype(jnp.int32), mask.astype(jnp.int32))
+    )(regs, ids_m)

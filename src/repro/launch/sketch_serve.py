@@ -43,6 +43,7 @@ from repro import engine
 from repro.engine import base, placement, plans
 from repro.graph import generators as gen
 from repro.kernels import registry
+from repro.launch import jaxenv
 from repro.serve import ContinuousServer, QueryServer, RotationPolicy
 from repro.serve.loadgen import ZipfSampler
 
@@ -99,6 +100,7 @@ def _client(server, edges: np.ndarray, n: int, requests: int,
 
 def main(argv: list[str] | None = None) -> None:
     """Entry point (see module docstring for the flags)."""
+    jaxenv.use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--scale", type=int, default=10,
                     help="rmat scale: n ~ 2**scale vertices")
@@ -164,6 +166,9 @@ def main(argv: list[str] | None = None) -> None:
     print(f"graph: n={n} m={len(edges)} (serving with {hold} edges held "
           f"back for live ingest); family={fam.name} backend={args.backend} "
           f"impl={args.impl} mode={mode}")
+    # the resolved kernel set, estimate_fallback included: a fallback is
+    # printed, never taken in silence
+    print(f"kernels: {eng.kernels}")
 
     plans.reset_trace_counts()
     t0 = time.monotonic()

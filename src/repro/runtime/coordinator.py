@@ -3,7 +3,8 @@
 Replaces the long-standing ``runtime/ft.py:coordinator()`` stub with the
 real control loop, realized at container scale: *hosts* are logical
 ingest workers over a ``jax.distributed``-style process group (the same
-abstraction the 8-fake-device harness stands in for), and the sharded
+abstraction that one device per host stands in for: virtual CPU
+devices, or the chips of one TPU host), and the sharded
 backend maps one register shard per live host. The loop composes three
 pieces that already existed separately:
 
@@ -34,9 +35,12 @@ from __future__ import annotations
 import os
 import sys
 
-if __name__ == "__main__" and "XLA_FLAGS" not in os.environ:
-    # --smoke needs a multi-device mesh; force it before jax loads.
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+from repro.launch import jaxenv
+
+if __name__ == "__main__":
+    # --smoke needs a multi-device mesh: virtual devices on the CPU only,
+    # before jax loads; an accelerator's visible devices are used as is.
+    jaxenv.virtual_cpu_devices(8)
 
 import math
 import time
@@ -287,28 +291,35 @@ def coordinator(edges, n: int, cfg=None, *, ft: FTConfig,
 def _smoke() -> int:
     """Kill-one-host CI smoke: recover and match an uninterrupted build.
 
-    Builds a small random graph on a 4-host sharded mesh, kills host 2
-    mid-stream, and asserts the recovered engine's degrees, union and
-    both ring-schedule neighborhood curves are bit-identical to a build
-    that never failed. Prints the runtime stats block and
-    ``FAILOVER_SMOKE_OK`` on success.
+    Builds a small random graph on a sharded mesh of up to 4 hosts (one
+    per visible device), kills host 1 mid-stream, and asserts the
+    recovered engine's degrees, union and both ring-schedule neighborhood
+    curves are bit-identical to a build that never failed. Prints the
+    runtime stats block and ``FAILOVER_SMOKE_OK`` on success.
     """
     import json
     import tempfile
 
+    import jax
+
     from repro.runtime.faults import KillHost
 
+    hosts = min(4, jax.device_count())
+    if hosts < 2:
+        raise SystemExit(
+            f"--smoke kills one of several hosts: needs >= 2 devices, "
+            f"{jax.device_count()} visible")
     rng = np.random.default_rng(7)
     n, m = 300, 4096
     edges = rng.integers(0, n, size=(m, 2), dtype=np.int64)
     with tempfile.TemporaryDirectory() as d:
         ft = FTConfig(ckpt_dir=os.path.join(d, "ckpt"), keep=3)
-        cc = CoordinatorConfig(hosts=4, block=256, ckpt_every=2)
+        cc = CoordinatorConfig(hosts=hosts, block=256, ckpt_every=2)
         eng, stats = coordinator(
             edges, n, ft=ft, config=cc, backend="sharded",
-            faults=FaultInjector(faults=(KillHost(host=2, at_block=8),)),
+            faults=FaultInjector(faults=(KillHost(host=1, at_block=8),)),
             replicate=[0, 1, 2, 3])
-        ref = engine.build(edges, n, backend="sharded", shards=4)
+        ref = engine.build(edges, n, backend="sharded", shards=hosts)
         assert stats["recoveries"] == 1 and stats["evictions"] == 1, stats
         assert np.array_equal(np.asarray(eng.degrees()),
                               np.asarray(ref.degrees())), "degrees diverge"
@@ -327,5 +338,6 @@ def _smoke() -> int:
 
 if __name__ == "__main__":
     if "--smoke" in sys.argv:
+        jaxenv.use_compile_cache()
         sys.exit(_smoke())
     print(__doc__)

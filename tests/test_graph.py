@@ -22,6 +22,14 @@ def test_rmat_shapes_and_powerlaw():
     assert deg.max() > 5 * deg.mean()
 
 
+def test_exact_degrees_match_adjacency():
+    e = gen.rmat(8, 8, seed=3)
+    n = 1 << 8
+    adj = exact.adjacency_lists(n, e)
+    np.testing.assert_array_equal(exact.degrees(n, e),
+                                  [len(a) for a in adj])
+
+
 def test_kronecker_triangle_formula_matches_exact():
     f, nf = gen.named_factor("wheel16")
     ke = gen.kronecker_edges(f, nf, f, nf)

@@ -1,0 +1,175 @@
+"""Compile-only checks against a described TPU v5e (no chip attached).
+
+The TPU compiler is installed with JAX, so every Pallas kernel and the
+main-path XLA programs can be compiled for a v5e that is described, not
+present. This catches what interpret mode cannot: tile-misaligned
+slices, unsupported 8-bit vector ops, SMEM layout mismatches, VMEM
+overflow, and programs that do not fit one chip's HBM.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load the TPU library at a time, and every
+test worker imports this file. Nothing here runs a kernel.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.hll import HLLConfig
+from repro.engine import plans
+from repro.kernels import ops, registry, tiles
+from repro.kernels.ertl_stats import ertl_stats
+from repro.kernels.hip_delta import hip_delta_rows
+from repro.kernels.hll_accumulate import hll_accumulate
+from repro.kernels.hll_estimate import hll_estimate_stats
+from repro.kernels.hll_propagate import hll_propagate
+from repro.kernels.intersection_stats import intersection_stats
+from repro.kernels.packing import row_width
+from repro.kernels.union_estimate import union_estimate_stats
+
+#: HBM of one v5e chip.
+V5E_HBM_BYTES = 16 * 2**30
+
+KERNELS = ("accumulate", "propagate", "union_estimate", "intersection_stats",
+           "estimate", "ertl_stats", "hip_delta")
+CASES = [(k, layout, p) for k in KERNELS for layout in ("byte", "packed")
+         for p in (8, 10) if not (k == "hip_delta" and layout == "packed")]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described v5e:2x2 host, with the compile cache off."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _max_rows(cfg: HLLConfig, layout: str) -> int:
+    """Rows of the largest panel the pallas VMEM bound admits."""
+    rows = tiles.PANEL_VMEM_BYTES // row_width(cfg.r, layout)
+    return rows - rows % tiles.tile_rows(jnp.uint8)
+
+
+def _kernel_call(kind: str, cfg: HLLConfig, layout: str, v: int):
+    """(fn, arg dtypes/shapes) compiling one kernel at a v-row panel."""
+    w = row_width(cfg.r, layout)
+    panel = ((v, w), jnp.uint8)
+    edges = ((4096,), jnp.int32)
+    if kind == "accumulate":
+        return (lambda r, a, k, m: hll_accumulate(
+                    r, a, k, m, p=cfg.p, layout=layout, interpret=False),
+                [panel, edges, ((4096,), jnp.uint32), ((4096,), jnp.bool_)])
+    if kind == "propagate":
+        return (lambda r, s, d: hll_propagate(r, s, d, layout=layout,
+                                              interpret=False),
+                [panel, edges, edges])
+    if kind == "union_estimate":
+        return (lambda r, i, m: union_estimate_stats(r, i, m, layout=layout,
+                                                     interpret=False),
+                [panel, ((64, 16), jnp.int32), ((64, 16), jnp.bool_)])
+    if kind == "intersection_stats":
+        return (lambda r, pr: intersection_stats(r, pr, cfg.q, layout=layout,
+                                                 interpret=False),
+                [panel, ((256, 2), jnp.int32)])
+    if kind == "estimate":
+        return (lambda r: hll_estimate_stats(r, layout=layout,
+                                             interpret=False), [panel])
+    if kind == "ertl_stats":
+        pairs = ((4096, w), jnp.uint8)
+        return (lambda a, b: ertl_stats(a, b, cfg.q, layout=layout,
+                                        interpret=False), [pairs, pairs])
+    return (lambda a, b: hip_delta_rows(a, b, interpret=False),
+            [panel, panel])
+
+
+def _compile(fn, shapes, sharding):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("kind,layout,p", CASES)
+def test_pallas_kernel_compiles_at_vmem_bound(one_chip, kind, layout, p):
+    """Each kernel compiles for v5e (not interpreted) at the largest panel
+    ``registry.resolve`` admits."""
+    cfg = HLLConfig(p=p)
+    v = _max_rows(cfg, layout)
+    registry.resolve("pallas", cfg, layout=layout, rows=v)
+    fn, shapes = _kernel_call(kind, cfg, layout, v)
+    compiled = _compile(fn, shapes, one_chip)
+    assert compiled.as_text().count("tpu_custom_call") >= 1
+
+
+@pytest.mark.parametrize("layout", ["byte", "packed"])
+def test_vmem_bound_refuses_one_step_larger(layout):
+    """A panel one row tile past the bound fails at resolve, naming it."""
+    cfg = HLLConfig(p=10)
+    v = _max_rows(cfg, layout)
+    registry.resolve("pallas", cfg, layout=layout, rows=v)
+    with pytest.raises(ValueError, match=str(tiles.PANEL_VMEM_BYTES)):
+        registry.resolve("pallas", cfg, layout=layout,
+                         rows=v + tiles.tile_rows(jnp.uint8))
+    registry.resolve("ref", cfg, layout=layout, rows=v + 32)  # no bound
+
+
+def test_vmem_bound_refuses_engine_open():
+    """``engine.open`` with impl='pallas' refuses an oversized table
+    instead of compiling it or switching to ref."""
+    from repro import engine
+    cfg = HLLConfig(p=8)
+    n = _max_rows(cfg, "byte") + tiles.tile_rows(jnp.uint8)
+    with pytest.raises(ValueError, match="VMEM"):
+        engine.open(n, cfg, impl="pallas", layout="byte")
+
+
+# Graph500 scale 20, edgefactor 16 at p=8: the chip smoke's main phase.
+SCALE20_ROWS = 1 << 20
+SCALE20_DIRECTED = 1 << 25  # shape bucket of both orientations of ~16M edges
+
+
+def _fits(compiled) -> int:
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes
+    assert total < V5E_HBM_BYTES, (m.argument_size_in_bytes,
+                                   m.temp_size_in_bytes)
+    return total
+
+
+def test_ref_ingest_plan_fits_one_chip(one_chip):
+    cfg = HLLConfig(p=8)
+    block = 2 * 32768  # both orientations of one INGEST_BLOCK
+    shapes = [((SCALE20_ROWS, cfg.r), jnp.uint8), ((block,), jnp.int32),
+              ((block,), jnp.uint32), ((block,), jnp.bool_)]
+    compiled = _compile(
+        lambda r, a, k, m: ops.accumulate(r, a, k, cfg, mask=m, impl="ref"),
+        shapes, one_chip)
+    _fits(compiled)
+
+
+def test_ref_propagate_plan_fits_one_chip(one_chip):
+    cfg = HLLConfig(p=8)
+    plan = plans.build_propagate_plan(registry.resolve("ref", cfg))
+    e = ((SCALE20_DIRECTED,), jnp.int32)
+    shapes = [((SCALE20_ROWS, cfg.r), jnp.uint8), e, e,
+              ((SCALE20_DIRECTED,), jnp.bool_)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = plan.lower(*args).compile()
+    assert _fits(compiled) > SCALE20_DIRECTED * cfg.r  # the u8[E, r] gather
+    np.testing.assert_equal(compiled.as_text().count("tpu_custom_call"), 0)

@@ -314,12 +314,15 @@ class SketchEngine(abc.ABC):
         queries but not edge-replay queries, and never start tracking
         edges even if further blocks are ingested (their panel already
         holds contributions from unknown edges). Chunks appended by
-        :meth:`ingest` are consolidated lazily on first access.
+        :meth:`ingest` are consolidated lazily on first access (the span
+        ``ds.engine.routing.edges``).
         """
         if self._edges0 is None:
             return None
         if self._edge_chunks:
-            self._edges0 = np.concatenate([self._edges0] + self._edge_chunks)
+            with plans.span("ds.engine.routing.edges"):
+                self._edges0 = np.concatenate([self._edges0]
+                                              + self._edge_chunks)
             self._edge_chunks = []
         return self._edges0
 
@@ -329,13 +332,16 @@ class SketchEngine(abc.ABC):
         e = self.edges
         return 0 if e is None else len(e)
 
-    def _require_edges(self, query: str) -> np.ndarray:
-        e = self.edges
-        if e is None:
+    def _check_edges(self, query: str) -> None:
+        """Raise ValueError unless edges are tracked (consolidates nothing)."""
+        if self._edges0 is None:
             raise ValueError(
                 f"{query} re-reads the edge stream, but this engine was "
                 f"built without edges (from_regs without edges=...)")
-        return e
+
+    def _require_edges(self, query: str) -> np.ndarray:
+        self._check_edges(query)
+        return self.edges
 
     # ---------------------------------------------------------- ingestion
     def ingest(self, edge_block) -> "SketchEngine":
@@ -361,28 +367,34 @@ class SketchEngine(abc.ABC):
 
         Returns self (engines mutate in place), so calls chain. Raises
         :class:`SnapshotFrozen` on a read-only :meth:`snapshot` view.
+
+        The host part — validation, the int32 cast, block preparation,
+        uploads and the asynchronous dispatch — is the span
+        ``ds.engine.ingest``; the device work runs after it returns.
         """
         self._check_mutable("ingest")
-        raw = np.asarray(edge_block)
-        if raw.ndim != 2 or raw.shape[1] != 2:
-            raise ValueError(
-                f"edge_block must have shape (k, 2), got {raw.shape}")
-        if raw.shape[0] == 0:
-            return self
-        plans.require_integer_ids(raw, "edge_block vertex ids")
-        lo, hi = int(raw.min()), int(raw.max())  # before the int32 cast:
-        if lo < 0 or hi >= self.n:               # ids >= 2^31 must not wrap
-            raise ValueError(
-                f"edge block contains vertex ids [{lo}, {hi}] outside the "
-                f"engine's universe [0, {self.n}) fixed at open() time")
-        block = np.ascontiguousarray(raw, dtype=np.int32)
-        self._release_lease()  # never donate a panel a snapshot still reads
-        for s in range(0, len(block), self.INGEST_BLOCK):
-            self._accumulate_block(block[s:s + self.INGEST_BLOCK])
-        self._version += 1
-        if self._edges0 is not None:
-            self._edge_chunks.append(block)
-        self._invalidate_edge_caches()
+        with plans.span("ds.engine.ingest"):
+            raw = np.asarray(edge_block)
+            if raw.ndim != 2 or raw.shape[1] != 2:
+                raise ValueError(
+                    f"edge_block must have shape (k, 2), got {raw.shape}")
+            if raw.shape[0] == 0:
+                return self
+            plans.require_integer_ids(raw, "edge_block vertex ids")
+            lo, hi = int(raw.min()), int(raw.max())  # before the int32 cast:
+            if lo < 0 or hi >= self.n:           # ids >= 2^31 must not wrap
+                raise ValueError(
+                    f"edge block contains vertex ids [{lo}, {hi}] outside "
+                    f"the engine's universe [0, {self.n}) fixed at open() "
+                    f"time")
+            block = np.ascontiguousarray(raw, dtype=np.int32)
+            self._release_lease()  # never donate a panel a snapshot reads
+            for s in range(0, len(block), self.INGEST_BLOCK):
+                self._accumulate_block(block[s:s + self.INGEST_BLOCK])
+            self._version += 1
+            if self._edges0 is not None:
+                self._edge_chunks.append(block)
+            self._invalidate_edge_caches()
         return self
 
     def ingest_stream(self, stream) -> "SketchEngine":
@@ -678,7 +690,9 @@ class SketchEngine(abc.ABC):
         """d̃(x) for every vertex x < n (the eponymous degree query)."""
         fn = self._plan("degrees", builder=lambda: plans.build_degrees_plan(
             self.cfg, self.kernels))
-        return np.asarray(fn(self._regs))[: self.n]
+        out = fn(self._regs)
+        with plans.span("ds.engine.query.fetch"):
+            return np.asarray(out)[: self.n]
 
     def union_size(self, vertex_sets):
         """|∪_{x in S} N(x)| for one vertex set or a batch of sets.
@@ -701,19 +715,24 @@ class SketchEngine(abc.ABC):
         single worker thread never re-scans the ids.
         """
         self._require_kind("union")
-        ids, mask = plans.pad_sets(sets)
         rs = self._replicas_current()
+        with plans.span("ds.engine.query.pad"):
+            ids, mask = plans.pad_sets(sets)
+            if rs is not None:
+                ids = placement.remap_ids(ids, rs.ids, self.n_pad)
         if rs is not None:
-            ids = placement.remap_ids(ids, rs.ids, self.n_pad)
             fn = self._plan(
                 "union_rep", bucket=ids.shape + (int(rs.rows.shape[0]),),
                 builder=lambda: plans.build_union_plan(
                     self.cfg, self.kernels, replicas=True))
-            return np.asarray(fn(self._regs, rs.rows, ids, mask))[: len(sets)]
-        fn = self._plan("union", bucket=ids.shape,
-                        builder=lambda: plans.build_union_plan(self.cfg,
-                                                               self.kernels))
-        return np.asarray(fn(self._regs, ids, mask))[: len(sets)]
+            out = fn(self._regs, rs.rows, ids, mask)
+        else:
+            fn = self._plan("union", bucket=ids.shape,
+                            builder=lambda: plans.build_union_plan(
+                                self.cfg, self.kernels))
+            out = fn(self._regs, ids, mask)
+        with plans.span("ds.engine.query.fetch"):
+            return np.asarray(out)[: len(sets)]
 
     def intersection_size(self, pairs, *, method: str = "mle",
                           iters: int | None = None):
@@ -741,23 +760,27 @@ class SketchEngine(abc.ABC):
         Serving hot path counterpart of :meth:`_union_presplit`.
         """
         self._require_kind("intersection")
-        ids, mask = plans.pad_pairs(arr)
         rs = self._replicas_current()
+        with plans.span("ds.engine.query.pad"):
+            ids, mask = plans.pad_pairs(arr)
+            if rs is not None:
+                ids = placement.remap_ids(ids, rs.ids, self.n_pad)
         if rs is not None:
-            ids = placement.remap_ids(ids, rs.ids, self.n_pad)
             fn = self._plan(
                 "intersection_rep",
                 bucket=(ids.shape[0], int(rs.rows.shape[0])),
                 extra=(method, iters),
                 builder=lambda: plans.build_intersection_plan(
                     self.cfg, self.kernels, method, iters, replicas=True))
-            return np.asarray(fn(self._regs, rs.rows, ids,
-                                 mask))[: arr.shape[0]]
-        fn = self._plan(
-            "intersection", bucket=(ids.shape[0],), extra=(method, iters),
-            builder=lambda: plans.build_intersection_plan(
-                self.cfg, self.kernels, method, iters))
-        return np.asarray(fn(self._regs, ids, mask))[: arr.shape[0]]
+            out = fn(self._regs, rs.rows, ids, mask)
+        else:
+            fn = self._plan(
+                "intersection", bucket=(ids.shape[0],), extra=(method, iters),
+                builder=lambda: plans.build_intersection_plan(
+                    self.cfg, self.kernels, method, iters))
+            out = fn(self._regs, ids, mask)
+        with plans.span("ds.engine.query.fetch"):
+            return np.asarray(out)[: arr.shape[0]]
 
     def query_batch(self, *, vertex_sets=None, pairs=None,
                     degrees: bool = False, method: str = "mle",
@@ -830,20 +853,22 @@ class SketchEngine(abc.ABC):
             return out
         # dummy panels for absent kinds: the traced body never touches
         # them, but the plan callable takes a fixed argument list
-        if sets:
-            u_ids, u_mask = plans.pad_sets(sets)
-        else:
-            u_ids = np.zeros((1, 1), np.int32)
-            u_mask = np.zeros((1, 1), bool)
-        if arr is not None and len(arr):
-            p_ids, p_mask = plans.pad_pairs(arr)
-        else:
-            p_ids = np.zeros((1, 2), np.int32)
-            p_mask = np.zeros((1,), bool)
         rs = self._replicas_current()
+        with plans.span("ds.engine.query.pad"):
+            if sets:
+                u_ids, u_mask = plans.pad_sets(sets)
+            else:
+                u_ids = np.zeros((1, 1), np.int32)
+                u_mask = np.zeros((1, 1), bool)
+            if arr is not None and len(arr):
+                p_ids, p_mask = plans.pad_pairs(arr)
+            else:
+                p_ids = np.zeros((1, 2), np.int32)
+                p_mask = np.zeros((1,), bool)
+            if rs is not None:
+                u_ids = placement.remap_ids(u_ids, rs.ids, self.n_pad)
+                p_ids = placement.remap_ids(p_ids, rs.ids, self.n_pad)
         if rs is not None:
-            u_ids = placement.remap_ids(u_ids, rs.ids, self.n_pad)
-            p_ids = placement.remap_ids(p_ids, rs.ids, self.n_pad)
             fn = self._plan(
                 "mixed_rep",
                 bucket=(u_ids.shape, p_ids.shape[0], int(rs.rows.shape[0])),
@@ -860,13 +885,14 @@ class SketchEngine(abc.ABC):
                                                        kinds, method, iters))
             raw = fn(self._regs, u_ids, u_mask, p_ids, p_mask)
         out = {}
-        if "degrees" in raw:
-            out["degrees"] = np.asarray(raw["degrees"])[: self.n]
-        if "union" in raw:
-            out["union"] = np.asarray(raw["union"])[: len(sets)]
-        if "intersection" in raw:
-            out["intersection"] = np.asarray(
-                raw["intersection"])[: arr.shape[0]]
+        with plans.span("ds.engine.query.fetch"):
+            if "degrees" in raw:
+                out["degrees"] = np.asarray(raw["degrees"])[: self.n]
+            if "union" in raw:
+                out["union"] = np.asarray(raw["union"])[: len(sets)]
+            if "intersection" in raw:
+                out["intersection"] = np.asarray(
+                    raw["intersection"])[: arr.shape[0]]
         return out
 
     # ------------------------------------------------- t-hop panel cache
@@ -928,7 +954,8 @@ class SketchEngine(abc.ABC):
 
     def _propagate_pass(self, regs: jax.Array, schedule: str) -> jax.Array:
         """One counted Algorithm 2 pass (the only propagate entry point)."""
-        out = self._propagate(regs, schedule)
+        with plans.span("ds.engine.propagate"):
+            out = self._propagate(regs, schedule)
         plans.record_event("propagate_pass")
         return out
 
@@ -952,13 +979,14 @@ class SketchEngine(abc.ABC):
         t_max = validate_t_max(t_max)
         self._require_kind("neighborhood")
         sched = self._canonical_schedule(schedule)
-        self._require_edges("neighborhood")
+        self._check_edges("neighborhood")  # the routing rebuild reads them
         est_fn = self._plan("degrees", builder=lambda: plans.
                             build_degrees_plan(self.cfg, self.kernels))
         local = np.zeros((t_max, self.n), dtype=np.float64)
         glob = np.zeros((t_max,), dtype=np.float64)
         for t, regs in enumerate(self._panels_up_to(t_max, sched), start=1):
-            est = np.asarray(est_fn(regs))[: self.n]
+            with plans.span("ds.engine.estimate.fetch"):
+                est = np.asarray(est_fn(regs))[: self.n]
             local[t - 1] = est
             glob[t - 1] = est.sum()
         return local, glob
@@ -1022,7 +1050,7 @@ class SketchEngine(abc.ABC):
         t_max = validate_t_max(t_max)
         self._require_kind("distance_histogram")
         sched = self._canonical_schedule(schedule)
-        self._require_edges("distance_histogram")
+        self._check_edges("distance_histogram")
         curve = self._hip_curve(t_max, sched)
         hist = self.family.hip_histogram(curve)
         return hist, hist.sum(axis=1)
@@ -1037,7 +1065,7 @@ class SketchEngine(abc.ABC):
         t_max = validate_t_max(t_max)
         self._require_kind("closeness")
         sched = self._canonical_schedule(schedule)
-        self._require_edges("closeness")
+        self._check_edges("closeness")
         return self.family.hip_closeness(self._hip_curve(t_max, sched))
 
     def effective_diameter(self, t_max: int, q: float = 0.9,
@@ -1053,7 +1081,7 @@ class SketchEngine(abc.ABC):
         t_max = validate_t_max(t_max)
         self._require_kind("effective_diameter")
         sched = self._canonical_schedule(schedule)
-        self._require_edges("effective_diameter")
+        self._check_edges("effective_diameter")
         glob = self._hip_curve(t_max, sched).sum(axis=1)
         return float(self.family.hip_effective_diameter(glob, q))
 

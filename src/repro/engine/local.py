@@ -126,12 +126,18 @@ class LocalEngine(SketchEngine):
 
     def _propagate(self, regs, schedule):
         if self._prop_routing is None:
-            e = self._require_edges("neighborhood")
-            src, dst, mask = plans.pad_routing(
-                np.concatenate([e[:, 0], e[:, 1]]),
-                np.concatenate([e[:, 1], e[:, 0]]))
-            self._prop_routing = (jnp.asarray(src), jnp.asarray(dst),
-                                  jnp.asarray(mask))
+            # the routing rebuild after each ingest: edge consolidation
+            # (ds.engine.routing.edges), padding, upload
+            with plans.span("ds.engine.routing"):
+                e = self._require_edges("neighborhood")
+                with plans.span("ds.engine.routing.pad"):
+                    src, dst, mask = plans.pad_routing(
+                        np.concatenate([e[:, 0], e[:, 1]]),
+                        np.concatenate([e[:, 1], e[:, 0]]))
+                with plans.span("ds.engine.routing.upload"):
+                    self._prop_routing = (jnp.asarray(src),
+                                          jnp.asarray(dst),
+                                          jnp.asarray(mask))
         src, dst, mask = self._prop_routing
         fn = self._plan("propagate", bucket=(int(src.shape[0]),),
                         builder=lambda: plans.

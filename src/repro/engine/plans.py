@@ -37,10 +37,23 @@ counted host-side via the companion *event counters*
 (:func:`record_event` / :func:`event_counts`): engines bump
 ``"propagate_pass"`` once per propagate pass they actually execute, so
 tests assert the panel cache by both counters (DESIGN.md §3c).
+
+Host *spans* (:func:`span`) time the host side of each layer boundary —
+serving drains, ingest preparation, query padding, device fetches, the
+propagation routing rebuild. A span is a ``jax.profiler.TraceAnnotation``
+(near-free with no profiler session; on the profiler's host plane, on the
+device ops' clock, when one runs) that also adds its host-clock duration
+to a per-name ``[count, total]`` entry under the counter lock, read by
+:func:`span_stats`. Every span name starts with ``ds.``, so a trace
+reduction selects the program's spans by prefix (DESIGN.md §3b).
+
+Every plan jit carries a stable name (:func:`_plan_jit`): a profile's
+``XLA Modules`` line reads ``jit_plan_union``, ``jit_plan_propagate``, ...
 """
 from __future__ import annotations
 
 import threading
+import time
 from collections import OrderedDict
 from dataclasses import dataclass
 
@@ -56,6 +69,7 @@ __all__ = [
     "require_integer_ids", "PlanKey",
     "PlanCache", "global_cache", "trace_counts", "reset_trace_counts",
     "record_trace", "record_event", "event_counts", "reset_event_counts",
+    "span", "span_stats", "reset_span_stats",
     "build_degrees_plan", "build_union_plan",
     "build_intersection_plan", "build_mixed_plan", "build_merge_plan",
     "build_propagate_plan", "build_replica_gather_plan",
@@ -280,6 +294,60 @@ def reset_event_counts() -> None:
         _EVENT_COUNTS.clear()
 
 
+# ------------------------------------------------------------- host spans
+_SPAN_STATS: dict[str, list] = {}  # name -> [count, total seconds]
+
+
+class span:
+    """Time a host stretch as a profiler span and in :func:`span_stats`.
+
+    ``with span("ds.engine.ingest"): ...`` opens
+    ``jax.profiler.TraceAnnotation(name, **meta)`` (``meta`` shows as the
+    event's arguments in a profile) and, on exit, adds the stretch's
+    ``time.perf_counter`` duration to the name's entry. Spans nest; each
+    counts its own whole duration. After exit, ``start`` and ``seconds``
+    hold the stretch's start (``perf_counter``) and length, for callers
+    that keep their own window totals (``QueryServer.stats()``).
+    """
+
+    __slots__ = ("name", "start", "seconds", "_ann")
+
+    def __init__(self, name: str, **meta):
+        self.name = name
+        self.start = self.seconds = 0.0
+        self._ann = jax.profiler.TraceAnnotation(name, **meta)
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, typ, val, tb) -> bool:
+        self.seconds = sec = time.perf_counter() - self.start
+        self._ann.__exit__(typ, val, tb)
+        with _TRACE_LOCK:
+            try:
+                entry = _SPAN_STATS[self.name]
+                entry[0] += 1
+                entry[1] += sec
+            except KeyError:
+                _SPAN_STATS[self.name] = [1, sec]
+        return False
+
+
+def span_stats() -> dict[str, dict]:
+    """Snapshot of {span name: {"count", "total_ms"}} since the last reset."""
+    with _TRACE_LOCK:
+        return {k: {"count": c, "total_ms": t * 1e3}
+                for k, (c, t) in _SPAN_STATS.items()}
+
+
+def reset_span_stats() -> None:
+    """Zero the span totals (test fixtures)."""
+    with _TRACE_LOCK:
+        _SPAN_STATS.clear()
+
+
 # -------------------------------------------------------------- plan cache
 @dataclass(frozen=True)
 class PlanKey:
@@ -398,12 +466,24 @@ def global_cache() -> PlanCache:
 
 
 # ------------------------------------------------------------ plan builders
+def _plan_jit(kind: str, fn, **jit_kw):
+    """``jax.jit(fn)`` under the stable program name ``plan_<kind>``.
+
+    XLA names a program after the jitted function, so every plan body
+    (all named ``fn``) would trace as ``jit_fn``; renaming it makes a
+    profile's ``XLA Modules`` line and every op's module say which plan
+    ran (``jit_plan_propagate``).
+    """
+    fn.__name__ = fn.__qualname__ = f"plan_{kind}"
+    return jax.jit(fn, **jit_kw)
+
+
 def build_degrees_plan(cfg, kernels):
     """Plan: per-row degree estimates d̃(x) over the full register table."""
     def fn(regs):
         record_trace("degrees")
         return kernels.estimate_rows(regs, cfg)
-    return jax.jit(fn)
+    return _plan_jit("degrees", fn)
 
 
 def _union_body(regs, ids, mask, cfg, kernels):
@@ -450,7 +530,7 @@ def build_union_plan(cfg, kernels, replicas: bool = False):
         def fn(regs, ids, mask):
             record_trace("union")
             return _union_body(regs, ids, mask, cfg, kernels)
-    return jax.jit(fn)
+    return _plan_jit("union_rep" if replicas else "union", fn)
 
 
 def build_intersection_plan(cfg, kernels, method: str, iters: int,
@@ -479,7 +559,7 @@ def build_intersection_plan(cfg, kernels, method: str, iters: int,
             record_trace("intersection")
             return _intersection_body(regs, pairs, mask, cfg, kernels,
                                       method, iters)
-    return jax.jit(fn)
+    return _plan_jit("intersection_rep" if replicas else "intersection", fn)
 
 
 def build_mixed_plan(cfg, kernels, kinds: tuple, method: str, iters: int,
@@ -519,7 +599,7 @@ def build_mixed_plan(cfg, kernels, kinds: tuple, method: str, iters: int,
         def fn(regs, u_ids, u_mask, p_ids, p_mask):
             record_trace("mixed")
             return compute(regs, regs, u_ids, u_mask, p_ids, p_mask)
-    return jax.jit(fn)
+    return _plan_jit("mixed_rep" if replicas else "mixed", fn)
 
 
 def build_replica_gather_plan():
@@ -534,7 +614,7 @@ def build_replica_gather_plan():
     def fn(regs, ids):
         record_trace("replica_gather")
         return regs[ids]
-    return jax.jit(fn)
+    return _plan_jit("replica_gather", fn)
 
 
 def build_merge_plan(layout: str = "byte"):
@@ -550,7 +630,7 @@ def build_merge_plan(layout: str = "byte"):
     def fn(mine, theirs):
         record_trace("merge")
         return packing.merge_rows(mine, theirs, layout=layout)
-    return jax.jit(fn, donate_argnums=(0,))
+    return _plan_jit("merge", fn, donate_argnums=(0,))
 
 
 def build_hip_delta_plan(kernels):
@@ -564,7 +644,7 @@ def build_hip_delta_plan(kernels):
     def fn(prev, cur):
         record_trace("hip_delta")
         return kernels.hip_delta(prev, cur)
-    return jax.jit(fn)
+    return _plan_jit("hip_delta", fn)
 
 
 def build_propagate_plan(kernels):
@@ -580,4 +660,4 @@ def build_propagate_plan(kernels):
     def fn(regs, src, dst, mask):
         record_trace("propagate")
         return kernels.propagate(regs, src, dst, mask=mask)
-    return jax.jit(fn)
+    return _plan_jit("propagate", fn)

@@ -26,6 +26,7 @@ import numpy as np
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed import sketch_dist as sd
+from repro.engine import plans
 from repro.engine.base import SketchEngine, bucket
 from repro.graph import stream as gstream
 from repro.kernels import packing
@@ -69,12 +70,13 @@ class ShardedEngine(SketchEngine):
         if self._dist_plan is None:
             with self._snap_lock:
                 if self._dist_plan is None:
-                    edges = self._require_edges(
-                        "the distributed routing plan")
-                    rs = self._replicas
-                    self._dist_plan = sd.build_plan(
-                        edges, self.n, self.shards,
-                        replica_ids=None if rs is None else rs.ids)
+                    with plans.span("ds.engine.routing"):
+                        edges = self._require_edges(
+                            "the distributed routing plan")
+                        rs = self._replicas
+                        self._dist_plan = sd.build_plan(
+                            edges, self.n, self.shards,
+                            replica_ids=None if rs is None else rs.ids)
         return self._dist_plan
 
     def _invalidate_edge_caches(self) -> None:

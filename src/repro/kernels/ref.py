@@ -3,6 +3,11 @@
 Each function here defines the exact semantics its kernel must reproduce;
 tests sweep shapes/dtypes and assert_allclose (exact equality for the
 integer register kernels) between kernel and oracle.
+
+Each oracle runs under a ``jax.named_scope`` named after its kernel op
+(``accumulate``, ``propagate``, ``estimate_rows``, ``union_estimate``,
+``intersection_stats``, ``hip_delta``), so a profile's op names say which
+op an XLA fusion belongs to (``jit_plan_union/union_estimate/...``).
 """
 from __future__ import annotations
 
@@ -24,9 +29,10 @@ def hip_delta_ref(prev: jax.Array, cur: jax.Array) -> jax.Array:
     against the pre-hop value. prev/cur: uint8[N, r] byte-layout panels
     with cur >= prev element-wise -> float32[N].
     """
-    grew = cur > prev
-    inv_p = jnp.exp2(prev.astype(jnp.float32))
-    return jnp.sum(jnp.where(grew, inv_p, 0.0), axis=-1)
+    with jax.named_scope("hip_delta"):
+        grew = cur > prev
+        inv_p = jnp.exp2(prev.astype(jnp.float32))
+        return jnp.sum(jnp.where(grew, inv_p, 0.0), axis=-1)
 
 
 def hll_accumulate_ref(regs: jax.Array, rows: jax.Array, buckets: jax.Array,
@@ -36,7 +42,8 @@ def hll_accumulate_ref(regs: jax.Array, rows: jax.Array, buckets: jax.Array,
     Padding convention: rho == 0 entries are no-ops (empty register value).
     regs: uint8[V, r]; rows/buckets: int32[E]; rhos: uint8[E].
     """
-    return regs.at[rows, buckets].max(rhos)
+    with jax.named_scope("accumulate"):
+        return regs.at[rows, buckets].max(rhos)
 
 
 def hll_propagate_ref(regs: jax.Array, src: jax.Array, dst: jax.Array,
@@ -46,8 +53,9 @@ def hll_propagate_ref(regs: jax.Array, src: jax.Array, dst: jax.Array,
     Reads always come from the *input* regs (the frozen D^{t-1}); the output
     starts as a copy of regs (Algorithm 2 line 23). mask=False rows no-op.
     """
-    gathered = jnp.where(mask[:, None], regs[src], jnp.uint8(0))
-    return regs.at[dst].max(gathered)
+    with jax.named_scope("propagate"):
+        gathered = jnp.where(mask[:, None], regs[src], jnp.uint8(0))
+        return regs.at[dst].max(gathered)
 
 
 def hll_estimate_ref(regs: jax.Array, alpha: float) -> tuple[jax.Array, jax.Array]:
@@ -58,10 +66,11 @@ def hll_estimate_ref(regs: jax.Array, alpha: float) -> tuple[jax.Array, jax.Arra
     — it is O(N) scalar work; the O(N*r) register reduction is the hot part.
     ``alpha`` is threaded for the fused raw estimate output convenience.
     """
-    x = regs.astype(jnp.float32)
-    s = jnp.sum(jnp.exp2(-x), axis=-1)
-    z = jnp.sum(regs == 0, axis=-1).astype(jnp.float32)
-    return s, z
+    with jax.named_scope("estimate_rows"):
+        x = regs.astype(jnp.float32)
+        s = jnp.sum(jnp.exp2(-x), axis=-1)
+        z = jnp.sum(regs == 0, axis=-1).astype(jnp.float32)
+        return s, z
 
 
 def union_estimate_ref(regs: jax.Array, ids: jax.Array, mask: jax.Array,
@@ -75,8 +84,9 @@ def union_estimate_ref(regs: jax.Array, ids: jax.Array, mask: jax.Array,
     plan (gather -> where(mask) -> max -> harmonic stats), restructured so
     a kernel can keep the merged rows on-chip.
     """
-    rows = jnp.where(mask[:, :, None], regs[ids], jnp.uint8(0))
-    return hll_estimate_ref(jnp.max(rows, axis=1), 0.0)
+    with jax.named_scope("union_estimate"):
+        rows = jnp.where(mask[:, :, None], regs[ids], jnp.uint8(0))
+        return hll_estimate_ref(jnp.max(rows, axis=1), 0.0)
 
 
 def intersection_stats_ref(regs: jax.Array, pa: jax.Array, pb: jax.Array,
@@ -91,15 +101,16 @@ def intersection_stats_ref(regs: jax.Array, pa: jax.Array, pb: jax.Array,
     Padding pairs gather row 0 like the old two-pass plan did; the caller
     masks the final estimates.
     """
-    a, b = regs[pa], regs[pb]
-    stats = ertl_stats_ref(a, b, q)
-    s_a, z_a = hll_estimate_ref(a, 0.0)
-    s_b, z_b = hll_estimate_ref(b, 0.0)
-    s_u, z_u = hll_estimate_ref(jnp.maximum(a, b), 0.0)
-    sz = jnp.stack([jnp.stack([s_a, z_a], axis=-1),
-                    jnp.stack([s_b, z_b], axis=-1),
-                    jnp.stack([s_u, z_u], axis=-1)], axis=-2)
-    return stats, sz
+    with jax.named_scope("intersection_stats"):
+        a, b = regs[pa], regs[pb]
+        stats = ertl_stats_ref(a, b, q)
+        s_a, z_a = hll_estimate_ref(a, 0.0)
+        s_b, z_b = hll_estimate_ref(b, 0.0)
+        s_u, z_u = hll_estimate_ref(jnp.maximum(a, b), 0.0)
+        sz = jnp.stack([jnp.stack([s_a, z_a], axis=-1),
+                        jnp.stack([s_b, z_b], axis=-1),
+                        jnp.stack([s_u, z_u], axis=-1)], axis=-2)
+        return stats, sz
 
 
 def ertl_stats_ref(a: jax.Array, b: jax.Array, q: int) -> jax.Array:

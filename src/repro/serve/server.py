@@ -44,6 +44,7 @@ live writer.
 """
 from __future__ import annotations
 
+import bisect
 import threading
 import time
 from collections import OrderedDict, deque
@@ -57,6 +58,11 @@ from repro.engine.base import validate_t_max
 __all__ = ["QueryServer", "ServerClosed", "note_access", "to_native"]
 
 _LATENCY_WINDOW = 8192  # per-kind latency samples kept for the stats
+#: queue-wait samples kept for ``stats()["queue_wait_ms"]`` percentiles —
+#: a minute of requests at ~2,000/s
+_QUEUE_WAIT_WINDOW = 1 << 17
+#: the serving thread's time, split as ``stats()["worker_s"]`` reports it
+_WORKER_PARTS = ("wait", "ingest", "query", "account")
 
 #: kinds the mixed-kind fused program (DESIGN.md §10) can answer — a
 #: contiguous drained run of these coalesces into one segment and, when
@@ -145,11 +151,8 @@ class _KindStats:
             r.t_done = now
             lat = now - r.t_submit
             self.latencies.append(lat)
-            ms = lat * 1e3
-            for i, edge in enumerate(_HIST_EDGES_MS):
-                if ms <= edge:
-                    self.hist[i] += 1
-                    break
+            # first bucket whose upper bound is >= the latency
+            self.hist[bisect.bisect_left(_HIST_EDGES_MS, lat * 1e3)] += 1
 
     def snapshot(self) -> dict:
         """Stats dict: counters, p50/p99/p999 and the non-empty buckets."""
@@ -237,13 +240,14 @@ def serve_segment(eng, seg: list[_Request], epoch: int) -> int:
     Fills ``result``/``error`` and tags ``epoch`` on every request; the
     caller sets ``done`` (after recording stats) and owns any locking.
     A mixed-kind segment rides the fused program when it can (the return
-    value counts those launches, 0 or 1).
+    value counts those launches, 0 or 1). The whole call is the span
+    ``ds.serve.segment``.
     """
-    if len({r.kind for r in seg}) > 1:
-        return _serve_fused(eng, seg, epoch)
-    kind = seg[0].kind
-    _SERVE_BY_KIND[kind](eng, seg, epoch)
-    return 0
+    with plans.span("ds.serve.segment", requests=len(seg), epoch=epoch):
+        if len({r.kind for r in seg}) > 1:
+            return _serve_fused(eng, seg, epoch)
+        _SERVE_BY_KIND[seg[0].kind](eng, seg, epoch)
+        return 0
 
 
 def _serve_fused(eng, seg: list[_Request], epoch: int) -> int:
@@ -493,6 +497,12 @@ class QueryServer:
         self._fused_batches = 0
         self._latency_window = int(latency_window)
         self._trace_base = plans.trace_counts()  # delta baseline for stats
+        self._span_base = plans.span_stats()    # likewise for the spans
+        self._queue_waits: deque = deque(maxlen=_QUEUE_WAIT_WINDOW)
+        self._queue_wait_count = 0
+        self._worker_s = dict.fromkeys(_WORKER_PARTS, 0.0)
+        self._t_reset = time.perf_counter()  # worker_s window start
+        self._t_drain = 0.0  # time.monotonic() the current drain began
         # runtime block schema parity with ContinuousServer (DESIGN.md
         # §14): the epoch-barrier server has no failover writer, so only
         # the worker's drain heartbeats ever move
@@ -741,11 +751,33 @@ class QueryServer:
         here only ``heartbeats_seen`` (worker queue drains) moves; the
         epoch-barrier server has no failover-aware writer to evict or
         recover.
+
+        Host time, over the window since the last :meth:`reset_stats`
+        (DESIGN.md §3b): ``queue_wait_ms`` (``p50``/``p95``/``p99`` and
+        ``count``) is each query request's wait from submit to the start
+        of the drain that served it (ingest and replicate barriers
+        excluded; percentiles over the last 131,072); ``worker_s`` splits
+        the serving thread's seconds — ``window`` (since the reset),
+        ``wait`` (idle on the queue, span ``ds.serve.wait``), ``ingest``
+        (``ds.serve.ingest``), ``query`` (``ds.serve.segment``) and
+        ``account`` (``ds.serve.account``): ``wait / window`` near 0 means
+        the single serving thread is saturated; ``spans`` is the
+        :func:`repro.engine.plans.span_stats` delta (``{name: {"count",
+        "total_ms"}}``), process-wide, so it also holds engine spans such
+        as ``ds.engine.query.fetch``.
         """
         with self._cv:
             out: dict = {"epoch": self._epoch,
                          "queue_depth": len(self._queue),
                          "runtime": dict(self._runtime)}
+            waits = np.asarray(self._queue_waits, dtype=np.float64) * 1e3
+            out["queue_wait_ms"] = {
+                f"p{q}": float(np.percentile(waits, q)) if waits.size
+                else None for q in (50, 95, 99)}
+            out["queue_wait_ms"]["count"] = self._queue_wait_count
+            out["worker_s"] = dict(
+                window=time.perf_counter() - self._t_reset,
+                **self._worker_s)
             total = 0
             for kind, s in self._stats.items():
                 out[kind] = s.snapshot()
@@ -760,6 +792,13 @@ class QueryServer:
         out["plan_traces"] = {  # programs compiled since THIS server opened
             k: v - self._trace_base.get(k, 0) for k, v in now_traces.items()
             if v - self._trace_base.get(k, 0) > 0}
+        spans, base = plans.span_stats(), self._span_base
+        out["spans"] = {}
+        for k, v in spans.items():
+            b = base.get(k, {"count": 0, "total_ms": 0.0})
+            if v["count"] > b["count"]:
+                out["spans"][k] = {"count": v["count"] - b["count"],
+                                   "total_ms": v["total_ms"] - b["total_ms"]}
         out["plan_cache"] = self._eng.plan_cache.stats()
         out["access"] = self._access.snapshot()
         out["family"] = self._eng.family.name
@@ -782,8 +821,22 @@ class QueryServer:
             self._fused_batches = 0
             self._t0 = None
             self._t_last = None
+            self._queue_waits.clear()
+            self._queue_wait_count = 0
+            self._worker_s = dict.fromkeys(_WORKER_PARTS, 0.0)
+            self._t_reset = time.perf_counter()
+            self._span_base = plans.span_stats()
         self._access.reset()
         self._trace_base = plans.trace_counts()
+
+    def _charge(self, part: str, start: float, seconds: float) -> None:
+        """Add the part of a worker stretch inside the stats window.
+
+        ``_cv``'s lock is reentrant, so the worker may call this holding it.
+        """
+        with self._cv:
+            self._worker_s[part] += max(
+                0.0, start + seconds - max(start, self._t_reset))
 
     # -------------------------------------------------------------- worker
     def _submit(self, kind: str, payload: tuple) -> _Request:
@@ -802,16 +855,23 @@ class QueryServer:
         try:
             while True:
                 with self._cv:
-                    while ((not self._queue or self._paused)
-                           and not self._closed):
-                        self._cv.wait()
+                    if (not self._queue or self._paused) and not self._closed:
+                        with plans.span("ds.serve.wait") as sp:
+                            while ((not self._queue or self._paused)
+                                   and not self._closed):
+                                self._cv.wait()
+                        self._charge("wait", sp.start, sp.seconds)
                     if self._closed and not self._queue:
                         return
                     batch = list(self._queue)
                     self._queue.clear()
                     self._runtime["heartbeats_seen"] += 1
+                    drain, epoch = self._runtime["heartbeats_seen"], self._epoch
+                    self._t_drain = time.monotonic()
                 try:
-                    self._serve(batch)
+                    with plans.span("ds.serve.drain", drain=drain,
+                                    requests=len(batch), epoch=epoch):
+                        self._serve(batch)
                 except Exception as e:  # noqa: BLE001 — never hang clients
                     for r in batch:
                         if not r.done.is_set():
@@ -836,32 +896,46 @@ class QueryServer:
         for seg in _segments(batch):
             if seg[0].kind == "ingest" and len({r.kind for r in seg}) == 1:
                 self._serve_ingest(seg)
+                self._account(seg, 0, query=False)
             elif (seg[0].kind == "replicate"
                   and len({r.kind for r in seg}) == 1):
                 self._serve_replicate(seg)
+                self._account(seg, 0, query=False)
             else:
+                t0 = time.perf_counter()
                 fused = serve_segment(self._eng, seg, self._epoch)
-                if fused:
-                    with self._cv:
-                        self._fused_batches += fused
-            note_access(self._access, seg)
-            now = time.monotonic()
-            with self._cv:
-                self._t_last = now
-                _note_served(self._stats, seg, now, self._latency_window)
+                self._charge("query", t0, time.perf_counter() - t0)
+                with plans.span("ds.serve.account") as sp:
+                    self._account(seg, fused, query=True)
+                self._charge("account", sp.start, sp.seconds)
             for r in seg:
                 r.done.set()
 
+    def _account(self, seg: list[_Request], fused: int, query: bool) -> None:
+        """Fold a served segment into the access counters and the stats."""
+        note_access(self._access, seg)
+        now = time.monotonic()
+        with self._cv:
+            self._t_last = now
+            self._fused_batches += fused
+            _note_served(self._stats, seg, now, self._latency_window)
+            if query:
+                self._queue_waits.extend(self._t_drain - r.t_submit
+                                         for r in seg)
+                self._queue_wait_count += len(seg)
+
     def _serve_ingest(self, run: list[_Request]) -> None:
         for r in run:
-            try:
-                self._eng.ingest(r.payload[0])
-            except Exception as e:  # noqa: BLE001
-                r.error = e
-                continue
-            with self._cv:
-                self._epoch += 1
-                r.result = r.epoch = self._epoch
+            with plans.span("ds.serve.ingest") as sp:
+                try:
+                    self._eng.ingest(r.payload[0])
+                except Exception as e:  # noqa: BLE001
+                    r.error = e
+                else:
+                    with self._cv:
+                        self._epoch += 1
+                        r.result = r.epoch = self._epoch
+            self._charge("ingest", sp.start, sp.seconds)
 
     def _serve_replicate(self, run: list[_Request]) -> None:
         """Apply replica-set changes as a worker barrier (like ingest).

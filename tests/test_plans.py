@@ -295,3 +295,134 @@ def test_kernel_set_is_hashable_plan_key_material():
     b = registry.resolve("ref", CFG)
     assert a == b and hash(a) == hash(b)
     assert a != registry.resolve("pallas", CFG)
+
+
+# ------------------------------------------------------------ spans, names
+def test_span_nests_counts_and_accumulates():
+    plans.reset_span_stats()
+    with plans.span("ds.test.outer", step=1) as outer:
+        for _ in range(3):
+            with plans.span("ds.test.inner"):
+                pass
+    with plans.span("ds.test.outer"):
+        pass
+    st = plans.span_stats()
+    assert st["ds.test.inner"]["count"] == 3
+    assert st["ds.test.outer"]["count"] == 2
+    assert outer.seconds > 0
+    # the outer span holds its three inner ones; totals add per name
+    assert st["ds.test.outer"]["total_ms"] >= outer.seconds * 1e3 \
+        >= st["ds.test.inner"]["total_ms"] >= 0
+    plans.reset_span_stats()
+    assert plans.span_stats() == {}
+
+
+def test_span_counts_lose_no_update_across_threads():
+    import sys
+    import threading
+
+    plans.reset_span_stats()
+    threads, each = 16, 2000
+
+    def work():
+        for _ in range(each):
+            with plans.span("ds.test.threads"):
+                pass
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        pool = [threading.Thread(target=work) for _ in range(threads)]
+        for t in pool:
+            t.start()
+        for t in pool:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in pool)
+    assert plans.span_stats()["ds.test.threads"]["count"] == threads * each
+    plans.reset_span_stats()
+
+
+def test_engine_spans_land_beside_executed_ops_in_a_profile(graph, tmp_path):
+    import glob
+    import os
+
+    edges, n = graph
+    eng = engine.open(n, CFG, backend="local")
+    eng.ingest(edges)                       # compile outside the profile
+    eng.union_size([np.arange(3)])
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        eng.ingest(edges)
+        eng.union_size([np.arange(3)])
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    from jax.profiler import ProfileData
+    host = [(line.name, ev.name)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+    names = {name for _, name in host}
+    assert {"ds.engine.ingest", "ds.engine.query.fetch"} <= names
+    # the accumulate's scatter-max ran, on an executor line of the same file
+    assert any("scatter" in name for line, name in host if line != "python")
+
+
+def _lowered_plan(kind):
+    """``(plan, example arguments)`` for each plan builder, tiny shapes."""
+    kernels = registry.resolve("ref", CFG)
+    regs = np.zeros((16, CFG.r), np.uint8)
+    rep = np.zeros((8, CFG.r), np.uint8)
+    ids, mask = np.zeros((8, 8), np.int32), np.ones((8, 8), bool)
+    pairs, pmask = np.zeros((8, 2), np.int32), np.ones((8,), bool)
+    route = (np.zeros(8, np.int32), np.zeros(8, np.int32),
+             np.ones(8, bool))
+    mixed = ("degrees", "union", "intersection")
+    table = {
+        "degrees": (lambda: plans.build_degrees_plan(CFG, kernels),
+                    (regs,)),
+        "union": (lambda: plans.build_union_plan(CFG, kernels),
+                  (regs, ids, mask)),
+        "union_rep": (lambda: plans.build_union_plan(CFG, kernels, True),
+                      (regs, rep, ids, mask)),
+        "intersection": (lambda: plans.build_intersection_plan(
+            CFG, kernels, "ie", 1), (regs, pairs, pmask)),
+        "intersection_rep": (lambda: plans.build_intersection_plan(
+            CFG, kernels, "ie", 1, replicas=True),
+            (regs, rep, pairs, pmask)),
+        "mixed": (lambda: plans.build_mixed_plan(CFG, kernels, mixed, "ie",
+                                                 1),
+                  (regs, ids, mask, pairs, pmask)),
+        "mixed_rep": (lambda: plans.build_mixed_plan(
+            CFG, kernels, mixed, "ie", 1, replicas=True),
+            (regs, rep, ids, mask, pairs, pmask)),
+        "replica_gather": (plans.build_replica_gather_plan,
+                           (regs, np.zeros(8, np.int32))),
+        "merge": (plans.build_merge_plan, (regs, regs)),
+        "hip_delta": (lambda: plans.build_hip_delta_plan(
+            registry.resolve("ref", family="ads")), (regs, regs)),
+        "propagate": (lambda: plans.build_propagate_plan(kernels),
+                      (regs,) + route),
+    }
+    build, args = table[kind]
+    return build().lower(*args)
+
+
+@pytest.mark.parametrize("kind", [
+    "degrees", "union", "union_rep", "intersection", "intersection_rep",
+    "mixed", "mixed_rep", "replica_gather", "merge", "hip_delta",
+    "propagate"])
+def test_plan_programs_are_named_by_kind(kind):
+    lowered = _lowered_plan(kind)
+    text = lowered.as_text()
+    assert f"module @jit_plan_{kind} " in text
+    assert "jit_fn" not in text
+    # ops carry their kernel op's named scope below the program's name
+    scope = {"degrees": "estimate_rows", "union": "union_estimate",
+             "intersection": "intersection_stats", "propagate": "propagate",
+             "hip_delta": "hip_delta"}.get(kind.replace("_rep", ""))
+    if scope is not None:
+        assert f"jit(plan_{kind})/{scope}/" in lowered.as_text(
+            debug_info=True)

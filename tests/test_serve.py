@@ -443,3 +443,67 @@ def test_queue_depth_reported_while_paused(graph):
         a.wait()
         b.wait()
         assert srv.stats()["queue_depth"] == 0
+
+
+def _traffic(srv, edges, n):
+    """5 union and 3 intersection requests around two ingest barriers."""
+    for i in range(5):
+        srv.union_size([np.arange(i + 1), np.array([n - 1])])
+    srv.ingest(edges[:50])
+    for i in range(3):
+        srv.intersection_size(edges[i:i + 4])
+    srv.ingest(edges[50:90])
+    return 8
+
+
+def test_queue_wait_counts_query_requests_only(graph):
+    edges, n = graph
+    srv = QueryServer(_open(n, "local"))
+    srv.reset_stats()
+    queries = _traffic(srv, edges, n)
+    srv.close()
+    qw = srv.stats()["queue_wait_ms"]
+    assert qw["count"] == queries            # the two ingests excluded
+    assert 0 <= qw["p50"] <= qw["p95"] <= qw["p99"]
+
+
+def test_worker_time_and_drain_spans(graph):
+    edges, n = graph
+    srv = QueryServer(_open(n, "local"))
+    srv.reset_stats()
+    drains0 = srv.stats()["runtime"]["heartbeats_seen"]
+    _traffic(srv, edges, n)
+    srv.close()                  # every drain span has closed
+    st = srv.stats()
+    ws = st["worker_s"]
+    assert set(ws) == {"window", "wait", "ingest", "query", "account"}
+    assert all(v >= 0 for v in ws.values())
+    assert ws["wait"] + ws["ingest"] + ws["query"] + ws["account"] \
+        <= ws["window"]
+    assert ws["ingest"] > 0 and ws["query"] > 0 and ws["wait"] > 0
+    spans = st["spans"]
+    drains = st["runtime"]["heartbeats_seen"] - drains0
+    assert spans["ds.serve.drain"]["count"] == drains
+    assert spans["ds.serve.ingest"]["count"] == 2
+    assert spans["ds.engine.ingest"]["count"] == 2
+    assert spans["ds.serve.segment"]["count"] \
+        == spans["ds.serve.account"]["count"] >= 1
+    assert spans["ds.serve.segment"]["total_ms"] \
+        >= spans["ds.engine.query.fetch"]["total_ms"] > 0
+
+
+def test_reset_stats_zeroes_host_time(graph):
+    edges, n = graph
+    srv = QueryServer(_open(n, "local"))
+    _traffic(srv, edges, n)
+    srv.close()
+    before = srv.stats()
+    assert before["queue_wait_ms"]["count"] and before["spans"]
+    srv.reset_stats()
+    st = srv.stats()
+    assert st["queue_wait_ms"] == {"p50": None, "p95": None, "p99": None,
+                                   "count": 0}
+    assert st["worker_s"]["window"] < before["worker_s"]["window"]
+    assert all(st["worker_s"][k] == 0.0
+               for k in ("wait", "ingest", "query", "account"))
+    assert st["spans"] == {}

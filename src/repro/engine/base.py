@@ -179,6 +179,31 @@ class _PanelSet:
     panels: list = field(default_factory=list)
     aux: dict = field(default_factory=dict)
 
+
+@dataclass(frozen=True)
+class _Routing:
+    """The local propagate routing: directed edge slots on the device.
+
+    ``src``/``dst``/``mask`` are int32/int32/bool[cap], cap a power-of-two
+    shape bucket; slots ``[0, filled)`` hold both orientations of the
+    first ``covered`` tracked undirected edges, the rest are the masked
+    ``(0, 0)`` padding. Append-only ingest keeps the routing and the next
+    propagate appends the newer edges' slots after ``filled`` (a new
+    record: snapshots share the old one by reference).
+    """
+
+    src: jax.Array
+    dst: jax.Array
+    mask: jax.Array
+    filled: int
+    covered: int
+
+    @property
+    def cap(self) -> int:
+        """Directed slots the routing holds (its shape bucket)."""
+        return int(self.src.shape[0])
+
+
 # Normalization/bucketing moved to repro.engine.plans (DESIGN.md §3b);
 # re-exported here for callers that imported them from the engine core.
 bucket = plans.bucket
@@ -240,8 +265,7 @@ class SketchEngine(abc.ABC):
         self._edge_chunks: list[np.ndarray] = []
         self._plan_cache = plan_cache or plans.global_cache()
         self._version = 0
-        self._prop_routing: tuple[jax.Array, jax.Array, jax.Array] | None = \
-            None
+        self._prop_routing: _Routing | None = None
         self._panel_set: _PanelSet | None = None
         self._replicas: _ReplicaSet | None = None
         self._frozen = False        # True only on snapshot() views
@@ -328,9 +352,27 @@ class SketchEngine(abc.ABC):
 
     @property
     def m(self) -> int:
-        """Number of undirected edges ingested so far (0 if untracked)."""
-        e = self.edges
-        return 0 if e is None else len(e)
+        """Number of undirected edges ingested so far (0 if untracked).
+
+        Counts the chunks in place: nothing is consolidated.
+        """
+        if self._edges0 is None:
+            return 0
+        return len(self._edges0) + sum(len(c) for c in self._edge_chunks)
+
+    def _edges_from(self, start: int) -> np.ndarray:
+        """Tracked undirected edges ``[start, m)``, int32[m - start, 2].
+
+        Read from the chunks in place, so appending a short tail to the
+        propagate routing never consolidates the whole edge list.
+        """
+        parts, end = [], 0
+        for part in (self._edges0, *self._edge_chunks):
+            end += len(part)
+            if end > start:
+                parts.append(part[max(start - end + len(part), 0):])
+        return (np.concatenate(parts) if parts
+                else np.zeros((0, 2), np.int32))
 
     def _check_edges(self, query: str) -> None:
         """Raise ValueError unless edges are tracked (consolidates nothing)."""
@@ -394,7 +436,7 @@ class SketchEngine(abc.ABC):
             self._version += 1
             if self._edges0 is not None:
                 self._edge_chunks.append(block)
-            self._invalidate_edge_caches()
+            self._invalidate_edge_caches(appended=True)
         return self
 
     def ingest_stream(self, stream) -> "SketchEngine":
@@ -631,16 +673,20 @@ class SketchEngine(abc.ABC):
             self._regs = _clone_panel(self._regs)
             self._regs_leased = False
 
-    def _invalidate_edge_caches(self) -> None:
+    def _invalidate_edge_caches(self, appended: bool = False) -> None:
         """Drop caches derived from the edge list or register panel.
 
-        Called after every ingest/merge: the propagate routing may cover
-        new edges, and the materialized t-hop panels were computed from
-        the pre-donation register table — the panel set is keyed by
-        :attr:`version` so a stale set could never be *served*, but
-        dropping it here frees its device memory immediately.
+        Called after every ingest/merge: the materialized t-hop panels
+        were computed from the pre-donation register table — the panel
+        set is keyed by :attr:`version` so a stale set could never be
+        *served*, but dropping it here frees its device memory
+        immediately. ``appended`` says the edge list only grew at its end
+        (ingest): the local propagate routing is then kept, and the next
+        propagate appends the new edges to it; any other change (merge)
+        drops it.
         """
-        self._prop_routing = None
+        if not appended:
+            self._prop_routing = None
         self._panel_set = None
 
     # ----------------------------------------------------- plan caching

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.engine import plans
-from repro.engine.base import SketchEngine, bucket, pad_vertices
+from repro.engine.base import SketchEngine, _Routing, bucket, pad_vertices
 from repro.graph import stream as gstream
 from repro.kernels import registry
 
@@ -125,24 +125,85 @@ class LocalEngine(SketchEngine):
         return "local"
 
     def _propagate(self, regs, schedule):
-        if self._prop_routing is None:
-            # the routing rebuild after each ingest: edge consolidation
-            # (ds.engine.routing.edges), padding, upload
-            with plans.span("ds.engine.routing"):
-                e = self._require_edges("neighborhood")
-                with plans.span("ds.engine.routing.pad"):
-                    src, dst, mask = plans.pad_routing(
-                        np.concatenate([e[:, 0], e[:, 1]]),
-                        np.concatenate([e[:, 1], e[:, 0]]))
-                with plans.span("ds.engine.routing.upload"):
-                    self._prop_routing = (jnp.asarray(src),
-                                          jnp.asarray(dst),
-                                          jnp.asarray(mask))
-        src, dst, mask = self._prop_routing
-        fn = self._plan("propagate", bucket=(int(src.shape[0]),),
+        rt = self._routing()
+        fn = self._plan("propagate", bucket=(rt.cap,),
                         builder=lambda: plans.
                         build_propagate_plan(self.kernels))
-        return fn(regs, src, dst, mask)
+        return fn(regs, rt.src, rt.dst, rt.mask)
+
+    def _routing(self) -> _Routing:
+        """The propagate routing over every tracked edge.
+
+        Built or extended under the span ``ds.engine.routing``. Ingest
+        keeps the routing, so after one the routing covers a prefix
+        of the edge list: while the new edges' slots fit its bucket they
+        are appended on the device (``ds.engine.routing.extend``, event
+        ``routing_extend``), reading only the new edges; otherwise, or with
+        no routing, it is rebuilt from the whole list (``routing_full``).
+        Slot order differs from a full build's, the slot multiset does
+        not, and register max is order-free: the panels are bit-identical.
+        """
+        rt, m = self._prop_routing, self.m
+        if rt is not None and rt.covered == m:
+            return rt
+        with plans.span("ds.engine.routing"):
+            if rt is not None and rt.filled + 2 * (m - rt.covered) <= rt.cap:
+                with plans.span("ds.engine.routing.extend"):
+                    rt = self._extend_routing(rt,
+                                              self._edges_from(rt.covered))
+                plans.record_event("routing_extend")
+            else:
+                rt = self._build_routing()
+                plans.record_event("routing_full")
+        self._prop_routing = rt
+        return rt
+
+    def _build_routing(self) -> _Routing:
+        """Pad and upload both orientations of every tracked edge.
+
+        Also runs the extend plan of the new bucket once, on an all-masked
+        slice at ``filled`` (padding rewritten with padding), so a later
+        extend in this bucket never compiles.
+        """
+        e = self._require_edges("neighborhood")  # ds.engine.routing.edges
+        with plans.span("ds.engine.routing.pad"):
+            src, dst, mask = plans.pad_routing(
+                np.concatenate([e[:, 0], e[:, 1]]),
+                np.concatenate([e[:, 1], e[:, 0]]))
+        with plans.span("ds.engine.routing.upload"):
+            rt = _Routing(jnp.asarray(src), jnp.asarray(dst),
+                          jnp.asarray(mask), filled=2 * len(e),
+                          covered=len(e))
+        empty = np.zeros(0, np.int32)
+        self._extend_plan(rt.cap)(
+            rt.src, rt.dst, rt.mask, np.int32(rt.filled),
+            *plans.pad_routing(empty, empty, cap=2 * self.INGEST_BLOCK))
+        return rt
+
+    def _extend_routing(self, rt: _Routing, tail: np.ndarray) -> _Routing:
+        """Append both orientations of ``tail`` after ``rt.filled``.
+
+        Written in fixed slices of ``2 * INGEST_BLOCK`` slots, so every
+        extend in a bucket reuses one compiled plan. Returns a new record;
+        ``rt`` and its arrays stay as they were.
+        """
+        size = 2 * self.INGEST_BLOCK
+        fn = self._extend_plan(rt.cap)
+        fwd = np.concatenate([tail[:, 0], tail[:, 1]])
+        rev = np.concatenate([tail[:, 1], tail[:, 0]])
+        src, dst, mask = rt.src, rt.dst, rt.mask
+        for s in range(0, len(fwd), size):
+            src, dst, mask = fn(
+                src, dst, mask, np.int32(rt.filled + s),
+                *plans.pad_routing(fwd[s:s + size], rev[s:s + size],
+                                   cap=size))
+        return _Routing(src, dst, mask, filled=rt.filled + len(fwd),
+                        covered=rt.covered + len(tail))
+
+    def _extend_plan(self, cap: int):
+        return self._plan("routing_extend",
+                          bucket=(cap, 2 * self.INGEST_BLOCK),
+                          builder=plans.build_routing_extend_plan)
 
     def triangle_heavy_hitters(self, k, *, mode="edge", iters=30):
         """Algorithms 4/5 on one device (see base class for the contract).

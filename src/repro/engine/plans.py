@@ -72,7 +72,8 @@ __all__ = [
     "span", "span_stats", "reset_span_stats",
     "build_degrees_plan", "build_union_plan",
     "build_intersection_plan", "build_mixed_plan", "build_merge_plan",
-    "build_propagate_plan", "build_replica_gather_plan",
+    "build_propagate_plan", "build_routing_extend_plan",
+    "build_replica_gather_plan",
     "build_hip_delta_plan",
 ]
 
@@ -216,18 +217,20 @@ def normalize_pairs(pairs, n: int | None = None,
     return out, mask, arr.shape[0], scalar
 
 
-def pad_routing(src: np.ndarray, dst: np.ndarray,
+def pad_routing(src: np.ndarray, dst: np.ndarray, cap: int | None = None,
                 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Pad a directed edge routing to a power-of-two shape bucket.
 
     Returns ``(src int32[E'], dst int32[E'], mask bool[E'])`` with E' =
-    ``bucket(len(src))``. This is what keeps propagation plans shape-
-    bucketed: edge counts that land in the same bucket share one compiled
-    program instead of retracing per distinct edge count (DESIGN.md §3c);
-    padding slots are masked out inside :func:`build_propagate_plan`.
+    ``cap``, by default ``bucket(len(src))``. This is what keeps
+    propagation plans shape-bucketed: edge counts that land in the same
+    bucket share one compiled program instead of retracing per distinct
+    edge count (DESIGN.md §3c); padding slots are masked out inside
+    :func:`build_propagate_plan`.
     """
     m = len(src)
-    cap = bucket(max(m, 1))
+    if cap is None:
+        cap = bucket(max(m, 1))
     src_p = np.zeros((cap,), np.int32)
     dst_p = np.zeros((cap,), np.int32)
     mask = np.zeros((cap,), bool)
@@ -661,3 +664,27 @@ def build_propagate_plan(kernels):
         record_trace("propagate")
         return kernels.propagate(regs, src, dst, mask=mask)
     return _plan_jit("propagate", fn)
+
+
+def build_routing_extend_plan():
+    """Plan: write one fixed-size slice of slots into a padded routing.
+
+    Takes the routing ``(src, dst, mask)`` of :func:`pad_routing`, the
+    first slot to write ``at`` and a slice ``(s_src, s_dst, s_mask)`` of S
+    slots, its tail padded with ``(0, 0, False)``; returns the routing
+    with slots ``[at, at + S)`` replaced. Slots past the routing's end
+    are dropped, never clamped back below ``at`` as a dynamic update
+    slice would be, so the slice may overhang the bucket as long as its
+    real slots fit. The inputs are not donated: a snapshot may still hold
+    them. Keyed by ``(cap, S)``; ``at`` is traced, so one program serves
+    every offset.
+    """
+    def fn(src, dst, mask, at, s_src, s_dst, s_mask):
+        record_trace("routing_extend")
+        idx = at + jnp.arange(s_src.shape[0], dtype=jnp.int32)
+
+        def put(a, v):
+            return a.at[idx].set(v, mode="drop", indices_are_sorted=True,
+                                 unique_indices=True)
+        return put(src, s_src), put(dst, s_dst), put(mask, s_mask)
+    return _plan_jit("routing_extend", fn)

@@ -79,9 +79,9 @@ class ShardedEngine(SketchEngine):
                             replica_ids=None if rs is None else rs.ids)
         return self._dist_plan
 
-    def _invalidate_edge_caches(self) -> None:
+    def _invalidate_edge_caches(self, appended: bool = False) -> None:
         """Ingest/merge moved the edge list: drop plan + propagate caches."""
-        super()._invalidate_edge_caches()
+        super()._invalidate_edge_caches(appended)
         self._dist_plan = None
 
     def _on_replicas_changed(self) -> None:

@@ -178,3 +178,123 @@ def test_sharded_schedules_keyed_separately(graph):
     l3, _ = eng.neighborhood(2, schedule="auto")  # auto == ring: recompute
     assert _passes() == 3                     # (one set cached at a time)
     np.testing.assert_array_equal(l1, l3)
+
+
+# ------------------------------------------- incremental propagate routing
+#: (family, layout) cells of the local routing; ADS rows are byte-only
+FAMILY_LAYOUTS = [("hll", "byte"), ("hll", "packed"), ("ads", "byte")]
+
+#: slots per extend slice (2 * INGEST_BLOCK) in the routing tests, small so
+#: the rmat(8, 8) graph (1,285 edges, buckets of 2,048 and 4,096 slots)
+#: reaches every case
+_SLICE_BLOCK = 16
+
+#: base edges, ingest tails and the number of extends each case runs
+ROUTING_CASES = {
+    "fits": (900, [10, 6], 2),          # slots 1800 -> 1832 of 2048
+    "looped_slices": (900, [100], 1),   # 200 slots: seven 32-slot slices
+    "slice_overhangs_cap": (1010, [12], 1),  # 2020 + 32 > 2048 >= 2044
+    "crosses_bucket": (1000, [100], 0),  # 2200 > 2048: a full rebuild
+}
+
+
+def _family_cfg(family):
+    from repro.core import ads
+    return ads.ADSConfig(p=8) if family == "ads" else CFG
+
+
+def _routing_events() -> tuple[int, int]:
+    ev = plans.event_counts()
+    return ev.get("routing_full", 0), ev.get("routing_extend", 0)
+
+
+@pytest.mark.parametrize("case", sorted(ROUTING_CASES))
+@pytest.mark.parametrize("family,layout", FAMILY_LAYOUTS)
+def test_extended_routing_bit_identical_to_fresh_engine(graph, family,
+                                                        layout, case):
+    """Ingest then neighborhood, alternately: every answer and cached panel
+    equals a fresh engine's built from the same edges in one pass."""
+    edges, n = graph
+    cfg = _family_cfg(family)
+    base, tails, extends = ROUTING_CASES[case]
+    eng = engine.open(n, cfg, layout=layout, family=family)
+    eng.INGEST_BLOCK = _SLICE_BLOCK
+    eng.ingest(edges[:base])
+    eng.neighborhood(3)
+    plans.reset_event_counts()
+    hi = base
+    for k in tails:
+        hi += k
+        eng.ingest(edges[hi - k:hi])
+        got_l, got_g = eng.neighborhood(3)
+        fresh = engine.build(edges[:hi], n, cfg, layout=layout, family=family)
+        want_l, want_g = fresh.neighborhood(3)
+        np.testing.assert_array_equal(got_l, want_l)
+        np.testing.assert_array_equal(got_g, want_g)
+        for got, want in zip(eng._panel_set.panels, fresh._panel_set.panels,
+                             strict=True):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    rt = eng._prop_routing
+    assert (rt.covered, rt.filled) == (hi, 2 * hi)
+    assert rt.cap == plans.bucket(2 * (base if extends else hi))
+    # each fresh engine builds one full routing; the writer only extends,
+    # except where the tail crossed the bucket
+    assert _routing_events() == (len(tails) + (extends == 0), extends)
+
+
+def test_routing_extends_after_ingest_and_counts_events(graph):
+    edges, n = graph
+    eng = engine.open(n, CFG)
+    plans.reset_event_counts()
+    eng.ingest(edges[:900])
+    eng.neighborhood(3)
+    eng.ingest(edges[900:950])
+    eng.neighborhood(3)
+    assert _routing_events() == (1, 1)
+
+
+def test_merge_drops_routing_to_a_full_build(graph):
+    edges, n = graph
+    eng = _build(edges[:900], n, "local")
+    eng.neighborhood(2)
+    eng.merge(_build(edges[900:950], n, "local"))
+    assert eng._prop_routing is None
+    plans.reset_event_counts()
+    l, _ = eng.neighborhood(2)
+    assert _routing_events() == (1, 0)
+    np.testing.assert_array_equal(l, _build(edges[:950], n,
+                                            "local").neighborhood(2)[0])
+
+
+def test_ingest_alone_leaves_routing_untouched(graph):
+    """Ingest does no routing work: no event, no routing span; the next
+    propagate appends every block ingested since in one extend."""
+    edges, n = graph
+    eng = _build(edges[:900], n, "local")
+    eng.neighborhood(2)
+    rt = eng._prop_routing
+    plans.reset_event_counts()
+    plans.reset_span_stats()
+    for lo in range(900, 1000, 10):
+        eng.ingest(edges[lo:lo + 10])
+    assert eng._prop_routing is rt
+    assert _routing_events() == (0, 0)
+    assert not [k for k in plans.span_stats() if "routing" in k]
+    eng.neighborhood(2)
+    assert _routing_events() == (0, 1)
+    assert plans.span_stats()["ds.engine.routing.extend"]["count"] == 1
+    assert (rt.covered, rt.filled) == (900, 1800)   # the old record stays
+
+
+def test_extend_in_warmed_bucket_compiles_nothing(graph):
+    """The full build warms the extend plan of its bucket: an extend in the
+    same bucket adds nothing to the trace counters."""
+    edges, n = graph
+    eng = _build(edges[:900], n, "local")
+    eng._plan_cache = plans.PlanCache(maxsize=32)
+    eng.neighborhood(3)
+    plans.reset_trace_counts()
+    eng.ingest(edges[900:950])
+    eng.neighborhood(3)
+    assert plans.trace_counts() == {}
+    assert eng._prop_routing.covered == 950

@@ -134,6 +134,30 @@ class TestSnapshotSemantics:
                                   np.asarray(ref.degrees())), cut
 
 
+def test_snapshot_extends_its_own_routing(graph):
+    """The writer extends its propagate routing after a snapshot; the
+    snapshot shares the older routing and extends it from its own edges."""
+    edges, n = graph
+    eng = _build(edges[:900], n, "local")
+    eng.neighborhood(3)
+    shared = eng._prop_routing                  # covers 900 edges
+    eng.ingest(edges[900:1000])                 # kept, not yet extended
+    snap = eng.snapshot()                       # version at 1,000 edges
+    eng.ingest(edges[1000:1100])
+    w_local, _ = eng.neighborhood(3)            # the writer extends
+    assert eng._prop_routing.covered == 1100
+    plans.reset_event_counts()
+    s_local, s_glob = snap.neighborhood(3)
+    assert plans.event_counts().get("routing_extend", 0) == 1
+    assert snap._prop_routing.covered == 1000
+    assert (shared.covered, shared.filled) == (900, 1800)
+    ref_l, ref_g = _build(edges[:1000], n, "local").neighborhood(3)
+    np.testing.assert_array_equal(s_local, ref_l)
+    np.testing.assert_array_equal(s_glob, ref_g)
+    np.testing.assert_array_equal(
+        w_local, _build(edges[:1100], n, "local").neighborhood(3)[0])
+
+
 class TestRotationPolicy:
     def test_validation(self):
         with pytest.raises(ValueError):

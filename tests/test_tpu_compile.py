@@ -173,3 +173,16 @@ def test_ref_propagate_plan_fits_one_chip(one_chip):
     compiled = plan.lower(*args).compile()
     assert _fits(compiled) > SCALE20_DIRECTED * cfg.r  # the u8[E, r] gather
     np.testing.assert_equal(compiled.as_text().count("tpu_custom_call"), 0)
+
+
+def test_routing_extend_plan_compiles_at_scale20_bucket(one_chip):
+    """One 65,536-slot slice written into the 2^25-slot routing."""
+    plan = plans.build_routing_extend_plan()
+    s = 2 * 32768  # 2 * INGEST_BLOCK
+    shapes = [((SCALE20_DIRECTED,), jnp.int32), ((SCALE20_DIRECTED,),
+              jnp.int32), ((SCALE20_DIRECTED,), jnp.bool_), ((), jnp.int32),
+              ((s,), jnp.int32), ((s,), jnp.int32), ((s,), jnp.bool_)]
+    args = [jax.ShapeDtypeStruct(sh, d, sharding=one_chip)
+            for sh, d in shapes]
+    compiled = plan.lower(*args).compile()
+    assert _fits(compiled) >= 9 * SCALE20_DIRECTED  # the routing's bytes

@@ -272,17 +272,20 @@ def reset_trace_counts() -> None:
 _EVENT_COUNTS: dict[str, int] = {}
 
 
-def record_event(event: str) -> None:
-    """Bump the host-side *execution* counter for ``event``.
+def record_event(event: str, count: int = 1) -> None:
+    """Add ``count`` (default 1) to the host-side *execution* counter.
 
     Complement of :func:`record_trace`: trace counters count compiled
     programs, event counters count host-observed executions — engines bump
     ``"propagate_pass"`` once per Algorithm 2 pass actually run, which is
     how the t-hop panel cache's "zero passes on an unchanged engine"
     guarantee is asserted (a cached program re-run would never retrace).
+    Quantities are counted the same way: the sharded ingest adds its
+    routed slots to ``"route_slots"`` and their padding to
+    ``"route_padded"``.
     """
     with _TRACE_LOCK:
-        _EVENT_COUNTS[event] = _EVENT_COUNTS.get(event, 0) + 1
+        _EVENT_COUNTS[event] = _EVENT_COUNTS.get(event, 0) + int(count)
 
 
 def event_counts() -> dict[str, int]:

@@ -22,6 +22,7 @@ edge list only when a propagation or triangle query needs it.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
@@ -31,7 +32,7 @@ from repro.engine.base import SketchEngine, bucket
 from repro.graph import stream as gstream
 from repro.kernels import packing
 
-__all__ = ["ShardedEngine"]
+__all__ = ["ShardedEngine", "build_ingest_step"]
 
 _AXIS = "sketch"
 
@@ -142,9 +143,11 @@ class ShardedEngine(SketchEngine):
         mesh = cls._make_mesh(shards)
         n_pad, _ = sd.vertex_partition(n, shards)
         width = packing.row_width(cfg.r, layout)
-        regs = jax.device_put(np.zeros((n_pad, width), np.uint8),
-                              NamedSharding(mesh, P(_AXIS, None)))
-        return cls(regs, n, cfg, np.zeros((0, 2), np.int32), impl,
+        # zeroed on the devices: a host table would be the whole
+        # deployment's bytes (17.2 GB at scale 24, p=10) copied at open
+        zeros = jax.jit(lambda: jnp.zeros((n_pad, width), jnp.uint8),
+                        out_shardings=NamedSharding(mesh, P(_AXIS, None)))
+        return cls(zeros(), n, cfg, np.zeros((0, 2), np.int32), impl,
                    mesh=mesh, shards=shards, layout=layout)
 
     @classmethod
@@ -199,35 +202,34 @@ class ShardedEngine(SketchEngine):
         and the register panel is donated through the jitted shard_map, so
         the steady-state ingest loop allocates only the small routed index
         arrays.
+
+        The routing — grouping, per-shard fill and padding, uploads — is
+        the span ``ds.engine.ingest.route``; the event counters
+        ``route_slots`` and ``route_padded`` add the block's directed
+        slots and the padding slots of the ``shards x cap`` panels, so
+        ``route_padded / (route_slots + route_padded)`` is the share of
+        the scatter spent on padding (owner imbalance included).
         """
-        per = gstream.bucket_by_owner(chunk, self.n_pad, self.shards)
-        cap = bucket(max(max(len(p) for p in per), 1))
-        dst = np.zeros((self.shards, cap), np.int32)
-        key = np.zeros((self.shards, cap), np.uint32)
-        msk = np.zeros((self.shards, cap), bool)
-        for s, p in enumerate(per):
-            k = len(p)
-            dst[s, :k] = p[:, 0] - s * self.v_loc
-            key[s, :k] = p[:, 1].astype(np.uint32)
-            msk[s, :k] = True
-        fn = self._plan("ingest", bucket=(cap,), builder=self._make_ingest_fn)
-        sh = NamedSharding(self.mesh, P(_AXIS, None))
-        self._regs = fn(self._regs, jax.device_put(dst, sh),
-                        jax.device_put(key, sh), jax.device_put(msk, sh))
-
-    def _make_ingest_fn(self):
-        """Donated jitted shard_map accumulate step (per-capacity cached)."""
-        kernels, cfg = self.kernels, self.cfg
-
-        def body(regs_local, dst_local, key, mask):
-            return kernels.accumulate(regs_local, dst_local[0], key[0], cfg,
-                                      mask=mask[0])
-
-        f = jax.shard_map(
-            body, mesh=self.mesh,
-            in_specs=(P(_AXIS, None),) * 4, out_specs=P(_AXIS, None),
-            check_vma=(self.impl != "pallas"))
-        return jax.jit(f, donate_argnums=(0,))
+        with plans.span("ds.engine.ingest.route"):
+            per = gstream.bucket_by_owner(chunk, self.n_pad, self.shards)
+            cap = bucket(max(max(len(p) for p in per), 1))
+            dst = np.zeros((self.shards, cap), np.int32)
+            key = np.zeros((self.shards, cap), np.uint32)
+            msk = np.zeros((self.shards, cap), bool)
+            for s, p in enumerate(per):
+                k = len(p)
+                dst[s, :k] = p[:, 0] - s * self.v_loc
+                key[s, :k] = p[:, 1].astype(np.uint32)
+                msk[s, :k] = True
+            sh = NamedSharding(self.mesh, P(_AXIS, None))
+            routed = [jax.device_put(a, sh) for a in (dst, key, msk)]
+        slots = 2 * len(chunk)
+        plans.record_event("route_slots", slots)
+        plans.record_event("route_padded", self.shards * cap - slots)
+        fn = self._plan("ingest", bucket=(cap,),
+                        builder=lambda: build_ingest_step(
+                            self.mesh, self.kernels, self.cfg, self.impl))
+        self._regs = fn(self._regs, *routed)
 
     def _place_rows(self, full: np.ndarray) -> jax.Array:
         """Block-shard a full row table over the mesh axis (for merge)."""
@@ -264,3 +266,28 @@ class ShardedEngine(SketchEngine):
     def _save_extra(self):
         """Record the shard count so load() restores the same mesh shape."""
         return {"shards": self.shards}
+
+
+def build_ingest_step(mesh, kernels, cfg, impl: str):
+    """The sharded accumulate step over ``mesh``: one donated program.
+
+    Takes ``(regs, dst, key, mask)``, all block-sharded on the mesh axis:
+    ``regs`` the uint8[n_pad, w] table, the others ``[shards, cap]``
+    panels of shard-local rows, neighbour ids (hashed inside the
+    accumulate) and validity. Each shard scatter-maxes its own panel
+    into its own rows; nothing crosses chips. Named
+    ``shard_accumulate_donated``, so a profile tells it
+    (``jit_shard_accumulate_donated``) from the local step.
+    """
+    def body(regs_local, dst_local, key, mask):
+        return kernels.accumulate(regs_local, dst_local[0], key[0], cfg,
+                                  mask=mask[0])
+
+    step = jax.shard_map(
+        body, mesh=mesh, in_specs=(P(_AXIS, None),) * 4,
+        out_specs=P(_AXIS, None), check_vma=(impl != "pallas"))
+
+    def shard_accumulate_donated(regs, dst, key, mask):
+        return step(regs, dst, key, mask)
+
+    return jax.jit(shard_accumulate_donated, donate_argnums=(0,))

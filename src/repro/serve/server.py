@@ -498,6 +498,7 @@ class QueryServer:
         self._latency_window = int(latency_window)
         self._trace_base = plans.trace_counts()  # delta baseline for stats
         self._span_base = plans.span_stats()    # likewise for the spans
+        self._event_base = plans.event_counts()  # and the event counters
         self._queue_waits: deque = deque(maxlen=_QUEUE_WAIT_WINDOW)
         self._queue_wait_count = 0
         self._worker_s = dict.fromkeys(_WORKER_PARTS, 0.0)
@@ -764,7 +765,10 @@ class QueryServer:
         the single serving thread is saturated; ``spans`` is the
         :func:`repro.engine.plans.span_stats` delta (``{name: {"count",
         "total_ms"}}``), process-wide, so it also holds engine spans such
-        as ``ds.engine.query.fetch``.
+        as ``ds.engine.query.fetch``; ``events`` is the
+        :func:`repro.engine.plans.event_counts` delta alike (``{name:
+        count}``, e.g. the sharded ingest's ``route_slots`` and
+        ``route_padded``).
         """
         with self._cv:
             out: dict = {"epoch": self._epoch,
@@ -799,6 +803,9 @@ class QueryServer:
             if v["count"] > b["count"]:
                 out["spans"][k] = {"count": v["count"] - b["count"],
                                    "total_ms": v["total_ms"] - b["total_ms"]}
+        events, base = plans.event_counts(), self._event_base
+        out["events"] = {k: v - base.get(k, 0) for k, v in events.items()
+                         if v > base.get(k, 0)}
         out["plan_cache"] = self._eng.plan_cache.stats()
         out["access"] = self._access.snapshot()
         out["family"] = self._eng.family.name
@@ -826,6 +833,7 @@ class QueryServer:
             self._worker_s = dict.fromkeys(_WORKER_PARTS, 0.0)
             self._t_reset = time.perf_counter()
             self._span_base = plans.span_stats()
+            self._event_base = plans.event_counts()
         self._access.reset()
         self._trace_base = plans.trace_counts()
 

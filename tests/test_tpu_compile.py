@@ -13,15 +13,20 @@ test worker imports this file. Nothing here runs a kernel.
 from __future__ import annotations
 
 import os
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType, Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.hll import HLLConfig
 from repro.engine import plans
+from repro.engine.sharded import build_ingest_step
 from repro.kernels import ops, registry, tiles
 from repro.kernels.ertl_stats import ertl_stats
 from repro.kernels.hip_delta import hip_delta_rows
@@ -32,8 +37,16 @@ from repro.kernels.intersection_stats import intersection_stats
 from repro.kernels.packing import row_width
 from repro.kernels.union_estimate import union_estimate_stats
 
+# the collective kinds the benchmark's trace reader counts (bench/ sits
+# beside tests/ at the repo root)
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))))
+from bench.collectives import KINDS as COLLECTIVES  # noqa: E402
+
 #: HBM of one v5e chip.
 V5E_HBM_BYTES = 16 * 2**30
+#: the sharded engine's mesh axis
+SHARD_AXIS = "sketch"
 
 KERNELS = ("accumulate", "propagate", "union_estimate", "intersection_stats",
            "estimate", "ertl_stats", "hip_delta")
@@ -42,8 +55,8 @@ CASES = [(k, layout, p) for k in KERNELS for layout in ("byte", "packed")
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One device of a described v5e:2x2 host, with the compile cache off."""
+def v5e_2x2():
+    """A described v5e:2x2 host (four chips), with the compile cache off."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -57,9 +70,22 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    """One device of the described v5e:2x2 host."""
+    return SingleDeviceSharding(v5e_2x2.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(v5e_2x2):
+    """The sharded engine's 1-D mesh over all four described chips."""
+    return Mesh(np.asarray(v5e_2x2.devices), (SHARD_AXIS,),
+                axis_types=(AxisType.Auto,))
 
 
 def _max_rows(cfg: HLLConfig, layout: str) -> int:
@@ -186,3 +212,81 @@ def test_routing_extend_plan_compiles_at_scale20_bucket(one_chip):
             for sh, d in shapes]
     compiled = plan.lower(*args).compile()
     assert _fits(compiled) >= 9 * SCALE20_DIRECTED  # the routing's bytes
+
+
+# Graph500 scale 24 at p=10, row-sharded over the four chips: the sharded
+# service cell's table (2^24 x 1,024 B = 17.2 GB, 4.29 GB a chip).
+SCALE24_ROWS = 1 << 24
+_BYTES = {"pred": 1, "u8": 1, "s8": 1, "u16": 2, "s16": 2, "bf16": 2,
+          "f16": 2, "u32": 4, "s32": 4, "f32": 4}
+
+
+def _collectives(text: str) -> list[tuple[str, int]]:
+    """(HLO line, result bytes) of every collective op in a compiled text."""
+    kind = re.compile(r"[\s)](%s)(-start)?\(" % "|".join(COLLECTIVES))
+    out = []
+    for line in text.splitlines():
+        _, eq, rest = line.strip().partition(" = ")
+        hit = kind.search(rest) if eq else None
+        if hit is None:
+            continue
+        nbytes = 0
+        for dt, dims in re.findall(r"(\w+)\[([\d,]*)\]",
+                                   rest[:hit.start() + 1]):
+            nbytes += _BYTES.get(dt, 4) * int(np.prod(
+                [int(d) for d in dims.split(",") if d] or [1]))
+        out.append((line.strip(), nbytes))
+    return out
+
+
+def _sharded_table(mesh, cfg):
+    return jax.ShapeDtypeStruct((SCALE24_ROWS, cfg.r), jnp.uint8,
+                                sharding=NamedSharding(mesh,
+                                                       P(SHARD_AXIS, None)))
+
+
+@pytest.mark.parametrize("sets,pairs", [(8, 8), (1024, 8), (1024, 1024)])
+def test_sharded_mixed_plan_gathers_rows_not_the_panel(four_chips, sets,
+                                                       pairs):
+    """The service's fused union + intersection program on the 4-way
+    row-sharded scale-24 table: it fits each chip, and what crosses chips
+    is the gathered rows (a masked local gather per shard, summed), never
+    the panel."""
+    cfg = HLLConfig(p=10)
+    kernels = registry.resolve("ref", cfg, rows=SCALE24_ROWS)
+    plan = plans.build_mixed_plan(cfg, kernels, ("union", "intersection"),
+                                  "ie", 30)
+    rep = NamedSharding(four_chips, P())
+    args = [_sharded_table(four_chips, cfg)] + [
+        jax.ShapeDtypeStruct(s, d, sharding=rep) for s, d in (
+            ((sets, 3), jnp.int32), ((sets, 3), jnp.bool_),
+            ((pairs, 2), jnp.int32), ((pairs,), jnp.bool_))]
+    compiled = plan.lower(*args).compile()
+    assert _fits(compiled) >= SCALE24_ROWS // 4 * cfg.r
+    found = _collectives(compiled.as_text())
+    assert found, "a gather from a sharded table crosses chips"
+    gathered = (sets * 3 + 2 * pairs) * cfg.r
+    for line, nbytes in found:
+        assert str(SCALE24_ROWS // 4) not in line and \
+            str(SCALE24_ROWS) not in line, line
+    # union lanes pad 3 -> 4 in the tiled layout: at most 4/3 of the rows
+    assert sum(b for _, b in found) <= gathered * 4 // 3, found
+
+
+@pytest.mark.parametrize("cap", [1 << 14, 1 << 15, 1 << 16])
+def test_sharded_ingest_step_at_scale24(four_chips, cap):
+    """The donated sharded accumulate compiles at scale-24, p=10 widths for
+    every routed capacity one 32,768-edge chunk can need, in place, with
+    no collective."""
+    cfg = HLLConfig(p=10)
+    kernels = registry.resolve("ref", cfg, rows=SCALE24_ROWS)
+    step = build_ingest_step(four_chips, kernels, cfg, "ref")
+    sh = NamedSharding(four_chips, P(SHARD_AXIS, None))
+    args = [_sharded_table(four_chips, cfg)] + [
+        jax.ShapeDtypeStruct((4, cap), d, sharding=sh)
+        for d in (jnp.int32, jnp.uint32, jnp.bool_)]
+    compiled = step.lower(*args).compile()
+    m = compiled.memory_analysis()
+    assert _fits(compiled) < SCALE24_ROWS // 4 * cfg.r + (64 << 20)
+    assert m.alias_size_in_bytes == SCALE24_ROWS // 4 * cfg.r  # donated
+    assert _collectives(compiled.as_text()) == []
